@@ -6,10 +6,15 @@ heads / head_dim while the host-side scheduler stays mesh-agnostic.  This
 bench serves the same deterministic workload at n_devices ∈ {1, 2, 4, 8}
 and records the decode-throughput trajectory into ``BENCH_tp.json``.
 
-Each cell runs in a subprocess: device count on the host platform is fixed
-at process start (``--xla_force_host_platform_device_count``), so a single
-process cannot sweep it.  Every cell boots TWICE against one shared
-ProgramStore — the second boot must deserialize every program
+Each cell runs in a subprocess on the CPU backend (``JAX_PLATFORMS=cpu``):
+device count on the host platform is fixed at process start
+(``--xla_force_host_platform_device_count``), so a single process cannot
+sweep it — and a child must never claim an accelerator the parent may
+already hold.  Every number here is therefore a CPU run;
+``chip_smoke.py --chips 4`` is the tensor-parallel path on real chips.
+
+Every cell boots TWICE against one shared ProgramStore — the second boot
+must deserialize every program
 (``compile_s == 0``), demonstrating per-mesh-shape warm boot — and every
 cell's token streams are asserted identical to the 1-device engine's.
 
@@ -71,7 +76,8 @@ _CELL = """
         eng.drain_completed()
         best_tps = max(best_tps, stats["decode_tokens"] / max(dec_s, 1e-9))
     print(json.dumps({{"n": n, "decode_tok_per_s": best_tps,
-                       "streams": streams, "boot": boot}}))
+                       "streams": streams, "boot": boot,
+                       "backend": jax.default_backend()}}))
 """
 
 
@@ -79,6 +85,7 @@ def _run_cell(n: int, *, arch, store_dir, batch, max_len, prefill_len,
               max_new, repeats) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO / "src")
     code = textwrap.dedent(_CELL.format(
         n=n, arch=arch, store_dir=store_dir, batch=batch, max_len=max_len,
@@ -115,6 +122,7 @@ def run(smoke: bool = False, arch: str = "qwen3-0.6b"):
                 "cold_sources": sorted({p["source"]
                                         for p in cold["boot"].values()}),
                 "streams": cold["streams"],
+                "backend": cold["backend"],
             }
 
     # token-exactness across every device count — TP is an implementation
@@ -148,7 +156,7 @@ def run(smoke: bool = False, arch: str = "qwen3-0.6b"):
         "token_exact": token_exact,
         "warm_boot_per_mesh_shape": warm_boot_ok,
         "env": {"jax": __import__("jax").__version__,
-                "backend": __import__("jax").default_backend()},
+                "backend": results[counts[0]]["backend"]},
     }
     TP_JSON.write_text(json.dumps(record, indent=2) + "\n")
     if scaling_gated:
@@ -157,7 +165,7 @@ def run(smoke: bool = False, arch: str = "qwen3-0.6b"):
         ("tp_decode_speedup", speedup,
          f"{results[counts[-1]]['decode_tok_per_s']:.0f} tok/s at "
          f"{counts[-1]} dev vs {results[counts[0]]['decode_tok_per_s']:.0f}"
-         f" at 1 (host_cores={host_cores}, "
+         f" at 1 (CPU run, forced host devices; host_cores={host_cores}, "
          f"gated={scaling_gated}) -> {TP_JSON.name}"),
         ("tp_token_exact", float(token_exact),
          f"streams identical across n_devices={list(counts)}"),

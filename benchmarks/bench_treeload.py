@@ -1,8 +1,9 @@
 """Figure 2 measured: serial loader vs distributed tree loader.
 
-Runs both loaders on an 8-device host mesh in a subprocess (the benchmark
-process itself keeps the single real device) and reports measured wall
-times plus the host-link byte counts — the quantity the tree design is
+Runs both loaders on an 8-device host mesh in a CPU subprocess
+(``JAX_PLATFORMS=cpu``: the benchmark process may hold the accelerator,
+which a child can never share) and reports CPU wall times plus the
+host-link byte counts — the quantity the tree design is
 about: serial moves N x payload over the host link, tree moves 1 x.
 """
 from __future__ import annotations
@@ -19,9 +20,9 @@ SRC = str(Path(__file__).resolve().parent.parent / "src")
 _CODE = """
 import json, time
 import jax, numpy as np
-from repro import compat
 from repro.core import treeload
-mesh = compat.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 x = rng.standard_normal((512, 512)).astype(np.float32)   # 1 MB payload
 
@@ -46,6 +47,7 @@ print(json.dumps({"serial_us": t_serial * 1e6, "tree_us": t_tree * 1e6,
 def run() -> list:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(_CODE)],
                          capture_output=True, text=True, env=env, timeout=600)
@@ -54,9 +56,9 @@ def run() -> list:
     r = json.loads(out.stdout.strip().splitlines()[-1])
     rows = [
         ("treeload_serial_8dev", r["serial_us"],
-         f"us; host moves 8x{r['payload_mb']:.0f}MB"),
+         f"us (CPU run); host moves 8x{r['payload_mb']:.0f}MB"),
         ("treeload_tree_8dev", r["tree_us"],
-         f"us; host moves 1x{r['payload_mb']:.0f}MB + 3 ICI rounds; "
-         f"correct={r['correct']}"),
+         f"us (CPU run); host moves 1x{r['payload_mb']:.0f}MB + 3 ICI "
+         f"rounds; correct={r['correct']}"),
     ]
     return rows

@@ -17,6 +17,8 @@ sys.path.insert(0, os.path.join(_ROOT, "src"))
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="toy sizes for CI (<60 s total)")

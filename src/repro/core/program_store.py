@@ -27,9 +27,11 @@ Three pieces:
     Disk-backed map from (fingerprint, mesh shape, device count, jax/jaxlib
     version, backend) to a serialized executable, written atomically.  A
     miss — including version skew, topology change or a corrupt payload —
-    silently falls back to compile-and-store; programs that cannot be
-    serialized (host callbacks capture unpicklable state) are skipped and
-    counted, never fatal.
+    falls back to compile-and-store; programs that cannot be serialized
+    (host callbacks capture unpicklable state) are skipped, never fatal.
+    Every entry that fails to read or deserialize, and every executable
+    that fails to serialize, counts in ``ProgramStore.errors``, so a boot
+    that was meant to be warm can check that nothing fell back.
 """
 from __future__ import annotations
 
@@ -281,6 +283,8 @@ class ProgramStore:
         self.misses = 0
         self.puts = 0
         self.skipped = 0          # programs that refused to serialize
+        self.errors = 0           # failed serializes + unreadable or
+                                  # undeserializable entries
 
     # -- keying -------------------------------------------------------------
     def digest(self, spec: ProgramSpec, mesh=None) -> str:
@@ -305,6 +309,7 @@ class ProgramStore:
                 payload, in_tree, out_tree = pickle.load(f)
         except Exception:
             self.misses += 1
+            self.errors += 1
             return None
         self.hits += 1
         return payload, in_tree, out_tree
@@ -368,4 +373,5 @@ class ProgramStore:
         return {"dir": str(self.directory), "entries": len(entries),
                 "bytes": sum(e.get("bytes", 0) for e in entries.values()),
                 "hits": self.hits, "misses": self.misses,
-                "puts": self.puts, "skipped": self.skipped}
+                "puts": self.puts, "skipped": self.skipped,
+                "errors": self.errors}

@@ -61,6 +61,7 @@ class ProgramStats:
     lower_s: float = 0.0
     compile_s: float = 0.0
     load_s: float = 0.0            # hot-load (deserialize/install) time
+    store_s: float = 0.0           # serialize + write to the program store
     executions: int = 0
     last_exec_s: float = 0.0
     serialized_bytes: int = 0
@@ -151,7 +152,6 @@ class Syscore:
         structs = tree_structs(spec.abstract_args)
         t0 = time.perf_counter()
         if self.mesh is not None and not getattr(self.mesh, "empty", False):
-            from repro.compat import set_mesh
             shardings = tree_shardings(spec.abstract_args, self.rules,
                                        self.mesh)
             out_shardings = spec.out_shardings
@@ -163,7 +163,7 @@ class Syscore:
                 # outputs come back replicated
                 out_shardings = tree_shardings(spec.out_logical, self.rules,
                                                self.mesh)
-            with set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 jf = jax.jit(spec.fn, in_shardings=shardings,
                              out_shardings=out_shardings,
                              donate_argnums=spec.donate_argnums)
@@ -194,12 +194,15 @@ class Syscore:
         try:
             from jax.experimental.serialize_executable import \
                 deserialize_and_load
-            compiled = deserialize_and_load(payload, in_tree, out_tree)
+            compiled = deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=self._execution_devices())
         except Exception:
             # stale/incompatible entry that slipped past the env key —
             # reclassify the lookup as a miss and recompile
             self.store.hits -= 1
             self.store.misses += 1
+            self.store.errors += 1
             return None
         prog = Program(key=spec.key, compiled=compiled,
                        fingerprint=spec.fingerprint, source="store")
@@ -210,6 +213,15 @@ class Syscore:
                                 1e3 * prog.stats.load_s)
         return prog
 
+    def _execution_devices(self):
+        """The devices a program of this Syscore runs on: its mesh's, or
+        the default device.  A deserialized executable must be loaded onto
+        exactly these — left to itself it spans every visible device, and a
+        1-device program then expects one shard per device."""
+        if self.mesh is not None and not getattr(self.mesh, "empty", False):
+            return list(self.mesh.devices.flat)
+        return jax.devices()[:1]
+
     def _store_program(self, spec, prog: Program,
                        store: Optional[ProgramStore] = None) -> bool:
         """Write a compiled program to global memory; programs whose
@@ -219,6 +231,7 @@ class Syscore:
         store = store if store is not None else self.store
         if prog.serializable is False:
             return False
+        t0 = time.perf_counter()
         try:
             from jax.experimental.serialize_executable import serialize
             payload, in_tree, out_tree = serialize(prog.compiled)
@@ -226,9 +239,11 @@ class Syscore:
         except Exception:
             prog.serializable = False
             store.skipped += 1
+            store.errors += 1
             return False
         prog.serializable = True
         prog.stats.serialized_bytes = len(payload)
+        prog.stats.store_s = time.perf_counter() - t0
         return True
 
     def install_serialized(self, key: str, payload: bytes, in_tree,
@@ -238,7 +253,9 @@ class Syscore:
         load path."""
         from jax.experimental.serialize_executable import deserialize_and_load
         t0 = time.perf_counter()
-        compiled = deserialize_and_load(payload, in_tree, out_tree)
+        compiled = deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=self._execution_devices())
         prog = Program(key=key, compiled=compiled, source="serialized")
         prog.stats.load_s = time.perf_counter() - t0
         prog.stats.serialized_bytes = len(payload)
@@ -304,6 +321,7 @@ class Syscore:
                 k: {"lower_s": p.stats.lower_s,
                     "compile_s": p.stats.compile_s,
                     "load_s": p.stats.load_s,
+                    "store_s": p.stats.store_s,
                     "executions": p.stats.executions,
                     "serialized_bytes": p.stats.serialized_bytes,
                     "source": p.source,

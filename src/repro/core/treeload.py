@@ -46,9 +46,8 @@ def _broadcast_fn(mesh: Mesh, axis: str, ndim: int):
             xs = jnp.where(take, recv, xs)
         return xs
 
-    from repro.compat import shard_map
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=spec,
-                             out_specs=spec))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
+                                 out_specs=spec))
 
 
 def tree_broadcast_stacked(x: jax.Array, mesh: Mesh, axis: str) -> jax.Array:

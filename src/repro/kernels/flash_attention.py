@@ -101,12 +101,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     grid = (bh, sq // block_q, sk // block_k)
     q_offset = sk - sq
 
-    kwargs = {}
-    try:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        pass
     return pl.pallas_call(
         functools.partial(
             _kernel, block_q=block_q, block_k=block_k,
@@ -126,5 +120,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v)

@@ -45,12 +45,6 @@ def matmul(x: jax.Array, w: jax.Array, *, block_m: int = 128,
     k_steps = k // block_k
     grid = (m // block_m, n // block_n, k_steps)
 
-    kwargs = {}
-    try:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        pass
     return pl.pallas_call(
         functools.partial(_kernel, k_steps=k_steps),
         grid=grid,
@@ -62,5 +56,6 @@ def matmul(x: jax.Array, w: jax.Array, *, block_m: int = 128,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, w)

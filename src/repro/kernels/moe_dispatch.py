@@ -49,12 +49,6 @@ def moe_ffn(buf: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array, *,
     assert c % block_c == 0
     grid = (e, c // block_c)
 
-    kwargs = {}
-    try:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    except Exception:
-        pass
     return pl.pallas_call(
         _kernel,
         grid=grid,
@@ -67,5 +61,6 @@ def moe_ffn(buf: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array, *,
         out_specs=pl.BlockSpec((1, block_c, d), lambda ei, ci: (ei, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((e, c, d), buf.dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(buf, w1, w3, w2)

@@ -52,12 +52,6 @@ def rglru_scan(a: jax.Array, b: jax.Array, *, chunk: int = 256,
     chunks = s // chunk
     grid = (bsz, l // block_l, chunks)
 
-    kwargs = {}
-    try:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        pass
     h, hf = pl.pallas_call(
         functools.partial(_kernel, chunks=chunks),
         grid=grid,
@@ -75,6 +69,7 @@ def rglru_scan(a: jax.Array, b: jax.Array, *, chunk: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((block_l,), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, b)
     return h, hf

@@ -80,12 +80,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     xt = x.transpose(0, 2, 1, 3)                  # (B,H,S,P)
     dtt = dt.transpose(0, 2, 1)                   # (B,H,S)
 
-    kwargs = {}
-    try:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except Exception:
-        pass
     y, hf = pl.pallas_call(
         functools.partial(_kernel, chunks=chunks),
         grid=grid,
@@ -106,6 +100,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, xt, dtt, b, c)
     return y.transpose(0, 2, 1, 3), hf

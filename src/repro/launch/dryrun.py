@@ -1,11 +1,14 @@
 import os
 if __name__ == "__main__":
-    # CLI mode only: force 512 placeholder devices so the production meshes
-    # exist on a CPU host.  MUST run before any jax import (jax locks the
-    # device count at first init) — which is why it is gated: library
-    # importers (the autotuner's cost model, tests) must see the process's
-    # real device topology, not have it hijacked by a transitive import.
+    # CLI mode only: force 512 placeholder CPU devices so the production
+    # meshes exist on any host — and the CPU platform, so on a machine with
+    # a TPU the dry run never claims the chip.  MUST run before any jax
+    # import (jax locks the platform and device count at first init) —
+    # which is why it is gated: library importers (the autotuner's cost
+    # model, tests) must see the process's real device topology, not have
+    # it hijacked by a transitive import.
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
@@ -31,7 +34,6 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from repro import compat
 from repro.launch import hlo_analysis as ha
 from repro.launch import mesh as mesh_lib
 from repro.launch import roofline as rl
@@ -174,7 +176,7 @@ def compile_cell(arch: str, shape: str, *, multi_pod: bool,
            "mesh": "2x16x16" if multi_pod else "16x16",
            "n_devices": mesh.size, "knobs": knobs,
            "global_batch": spec.global_batch, "seq_len": spec.seq_len}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jf = jax.jit(step, in_shardings=shardings, out_shardings=out_shardings,
                      donate_argnums=spec.donate_argnums)
         t0 = time.time()
@@ -209,8 +211,6 @@ def compile_cell(arch: str, shape: str, *, multi_pod: bool,
     # XLA's cost_analysis counts while bodies ONCE — record it for reference
     # but derive the roofline from the loop-aware analyzer (hlo_analysis.py).
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):      # jax<=0.4 returns one dict per device
-        ca = ca[0] if ca else {}
     rec["xla_reported"] = {"flops": float(ca.get("flops", 0.0)),
                            "bytes": float(ca.get("bytes accessed", 0.0))}
 

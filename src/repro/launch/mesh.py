@@ -8,8 +8,6 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 POD_SHAPE = (16, 16)
 MULTI_POD_SHAPE = (2, 16, 16)
 
@@ -17,7 +15,8 @@ MULTI_POD_SHAPE = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def serving_mesh(n_devices: int = 0, axis: str = "model") -> jax.sharding.Mesh:
@@ -25,15 +24,15 @@ def serving_mesh(n_devices: int = 0, axis: str = "model") -> jax.sharding.Mesh:
     on the ``axis`` axis (default "model" — the axis the sharding rules map
     heads / kv_heads / ff / vocab / experts to).
 
-    One definition on purpose, routed through :func:`repro.compat.make_mesh`
-    so engine, tests and benchmarks build byte-identical meshes on the whole
-    pinned jax 0.4↔0.6 range — and so the ProgramStore's mesh-shape key
+    One definition on purpose, so engine, tests and benchmarks build
+    identical meshes — and the ProgramStore's mesh-shape key
     (``axis=size``) can never drift between producers.  ``n_devices`` <= 0
     means "every visible device".
     """
     n = n_devices if n_devices > 0 else len(jax.devices())
     assert n <= len(jax.devices()), (n, len(jax.devices()))
-    return compat.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def dp_size(mesh: jax.sharding.Mesh) -> int:

@@ -1088,10 +1088,18 @@ class ServingEngine:
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="the architecture at its published widths "
+                         "(default: the reduced preset)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--max-len", type=int, default=EngineConfig.max_len,
+                    help="per-slot cache length (prompts keep their last "
+                         "max_len // 2 tokens)")
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--store-dir", default=None,
                     help="persistent program store; a second run with the "
@@ -1120,7 +1128,8 @@ def main():
                          "programs compile against a 1-D 'model' mesh")
     args = ap.parse_args()
     config = EngineConfig(
-        batch=args.batch, store_dir=args.store_dir,
+        reduced=args.reduced, batch=args.batch, max_len=args.max_len,
+        store_dir=args.store_dir,
         paging=(PagingConfig(kv_block=args.kv_block,
                              arena_blocks=args.arena_blocks)
                 if args.paged else None),

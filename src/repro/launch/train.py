@@ -140,6 +140,8 @@ def train(arch: str, *, reduced: bool = True, steps: int = 100,
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--reduced", action="store_true", default=True)

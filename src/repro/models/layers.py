@@ -8,6 +8,7 @@ materializing 26B parameters on the CPU container.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -127,20 +128,29 @@ def apply_mlp(p: Params, x: jax.Array, rules, act=jax.nn.silu) -> jax.Array:
 # parameter materialization
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames=("leaves",))
+def _init_leaves(key, *, leaves):
+    """One program for a whole tree: ``leaves`` is ((shape, dtype, std),
+    ...), with ``std=None`` for a zero-initialized leaf."""
+    keys = jax.random.split(key, max(len(leaves), 1))
+    # the barrier keeps the scale out of the sampler's fusion, so the draws
+    # round exactly as they do op by op
+    return [jnp.zeros(shape, dtype) if std is None else
+            (jax.lax.optimization_barrier(
+                jax.random.normal(k, shape, jnp.float32)) * std).astype(dtype)
+            for (shape, dtype, std), k in zip(leaves, keys)]
+
+
 def materialize(abstract_tree, key: jax.Array, init_scale: float = 1.0):
-    """LogicalArray pytree -> initialized arrays (host-side, for real runs)."""
+    """LogicalArray pytree -> initialized arrays (host-side, for real runs).
+
+    Norm scales, biases and scalars start at zero; matrices are normal with
+    std ``init_scale / sqrt(fan_in)``.  The whole tree is drawn by one
+    compiled program, cached per tree shape."""
     leaves, treedef = jax.tree.flatten(
         abstract_tree, is_leaf=lambda x: isinstance(x, LogicalArray))
-    keys = jax.random.split(key, max(len(leaves), 1))
-    out = []
-    for la, k in zip(leaves, keys):
-        if len(la.shape) <= 1:  # norm scales / biases / scalars
-            if la.logical and la.logical[0] == "norm":
-                out.append(jnp.zeros(la.shape, la.dtype))
-            else:
-                out.append(jnp.zeros(la.shape, la.dtype))
-        else:
-            fan_in = la.shape[-2]
-            std = init_scale / (fan_in ** 0.5)
-            out.append((jax.random.normal(k, la.shape, jnp.float32) * std).astype(la.dtype))
-    return jax.tree.unflatten(treedef, out)
+    specs = tuple(
+        (la.shape, la.dtype,
+         None if len(la.shape) <= 1 else init_scale / (la.shape[-2] ** 0.5))
+        for la in leaves)
+    return jax.tree.unflatten(treedef, _init_leaves(key, leaves=specs))

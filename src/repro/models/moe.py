@@ -131,9 +131,8 @@ def apply_moe(cfg, p: Dict[str, Any], x: jax.Array,
                           e_local0=mi * n_local, n_local=n_local,
                           capacity=cap, model_axis="model", dp_axes=dp_axes)
 
-    from repro.compat import shard_map
     batch_axes = dp_axes if dp_axes else None
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),

@@ -589,6 +589,12 @@ def verify_decode(cfg, params, caches, tokens, *, rules):
     acceptance reproduces the sequential greedy stream exactly, never just
     approximately.  The scan amortizes S decode steps into one dispatch
     (the paper's re-execute-vs-reload lesson applied to the decode loop).
+    The bits match only while XLA emits the scan body as it emits the
+    top-level step.  Left to itself it hoists loop-invariant weight math
+    out of the loop (on the CPU, the hybrid family's ``1 + scale`` norm
+    weight, which changes the fused projection's rounding by one ulp), so
+    the body passes the weights through an optimization barrier tied to
+    the step's token.
 
     Rollback, per cache representation:
       * attention KV (dense or windowed non-ring): rejected positions sit
@@ -613,7 +619,11 @@ def verify_decode(cfg, params, caches, tokens, *, rules):
     orig = caches
 
     def body(c, tok):
-        logits, c2 = decode_step(cfg, params, c, tok[:, None], rules=rules)
+        # tie the weights to a per-step value so XLA cannot hoist their
+        # loop-invariant math (e.g. the norm's ``1 + scale``) out of the
+        # scan: the body then compiles as the top-level step does
+        p, tok = jax.lax.optimization_barrier((params, tok))
+        logits, c2 = decode_step(cfg, p, c, tok[:, None], rules=rules)
         y = greedy_token(cfg, logits[:, 0])
         rec = [leaf for path, leaf in
                jax.tree_util.tree_flatten_with_path(c2)[0]
@@ -752,7 +762,8 @@ def decode_horizon(cfg, params, caches, tokens, budget, *, rules,
 
     def body(carry, _):
         caches, tok, emitted, live = carry
-        logits, caches2 = decode_step(cfg, params, caches, tok, rules=rules,
+        p, tok = jax.lax.optimization_barrier((params, tok))  # as in verify
+        logits, caches2 = decode_step(cfg, p, caches, tok, rules=rules,
                                       live=live)
         y = jnp.where(live, greedy_token(cfg, logits[:, 0]), tok[:, 0])
         emitted = emitted + live.astype(jnp.int32)

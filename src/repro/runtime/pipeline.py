@@ -86,11 +86,10 @@ def pipeline_forward(stage_fn: Callable, stage_params, x_micro: jax.Array,
             jnp.where(stage == src, outputs, jnp.zeros_like(outputs)), axis)
         return outputs
 
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )
     return fn(stage_params, x_micro)

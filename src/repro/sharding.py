@@ -172,23 +172,7 @@ def constrain(x: jax.Array, logical: Tuple[Optional[str], ...],
 
 
 def get_abstract_mesh_or_none():
-    """The mesh the current trace resolves logical axes against, or None.
-
-    New jax exposes it as ``jax.sharding.get_abstract_mesh``; on the pinned
-    0.4 range that API doesn't exist, but ``compat.set_mesh`` enters the
-    legacy mesh context manager, whose mesh lives in the thread-local
-    resource env — fall back to it so ``constrain`` and the decode-KV
-    layout choice see the mesh on every supported jax.
-    """
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m is None or m.empty else m
-    except Exception:
-        return None
+    """The mesh the current trace resolves logical axes against (the one
+    ``jax.set_mesh`` entered), or None outside any mesh context."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m

@@ -1,0 +1,94 @@
+"""Compiles of the main serving path for one described TPU v5e chip.
+
+Nothing here runs on a chip: the TPU compiler compiles against a described
+``v5e:2x2`` topology, which refuses what the chip would refuse — a program
+that outgrows the 16 GB of HBM, a kernel the Mosaic backend cannot lower —
+at no chip time.  The topology is described only inside the ``one_chip``
+fixture, so collecting this file never loads the TPU library; where it
+cannot be described, every test here skips from that fixture.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import steps
+from repro.engine_config import EngineConfig
+from repro.kernels import ops
+from repro.models import registry
+from repro.sharding import make_rules, tree_structs
+
+HBM_BYTES = 16e9                # one TPU v5e chip
+ARCH = "qwen3-0.6b"             # at its published widths
+SERVE = EngineConfig(reduced=False, batch=8, max_len=2048)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described ``v5e:2x2``, with the persistent compilation
+    cache off: an entry compiled for a described chip cannot be read back
+    without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        mp.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+@pytest.fixture(scope="module")
+def serve_specs():
+    cfg = registry.get_config(ARCH, reduced=False)
+    return steps.serve_program_specs(cfg, make_rules(), SERVE)
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_slot", "prefill"])
+def test_serve_program_fits_one_v5e_chip(one_chip, serve_specs, program):
+    """Each program the dense engine hot-loads, compiled the way a
+    mesh-less Syscore compiles it, fits one chip's HBM — and the donated
+    KV cache is aliased to the output, not copied."""
+    spec = serve_specs[program]
+    args = _on(one_chip, tree_structs(spec.abstract_args))
+    compiled = jax.jit(spec.fn, donate_argnums=spec.donate_argnums).lower(
+        *args).compile()
+    mem = compiled.memory_analysis()
+    kv_bytes = sum(x.size * x.dtype.itemsize for x in
+                   jax.tree.leaves((args[1]["groups"], args[1]["tail"])))
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES, \
+        (program, mem)
+    assert mem.alias_size_in_bytes >= kv_bytes, (program, mem)
+
+
+def test_matmul_kernel_compiles_for_v5e(one_chip):
+    x = jax.ShapeDtypeStruct((1024, 1024), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((1024, 3072), jnp.bfloat16, sharding=one_chip)
+    text = ops.matmul.lower(x, w, impl="pallas").compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_kernel_compiles_for_v5e(one_chip):
+    q = jax.ShapeDtypeStruct((16, 1024, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((8, 1024, 128), jnp.bfloat16, sharding=one_chip)
+    text = ops.flash_attention.lower(q, kv, kv,
+                                     impl="pallas").compile().as_text()
+    assert "tpu_custom_call" in text

@@ -32,9 +32,9 @@ def test_tree_broadcast_equals_serial():
     res = _run("""
         import json
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.core import treeload
-        mesh = compat.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         rng = np.random.default_rng(0)
         x = rng.standard_normal((32, 16)).astype(np.float32)
         tree = treeload.tree_broadcast_replicate(x, mesh, "data")
@@ -52,9 +52,9 @@ def test_tree_broadcast_round_structure():
     res = _run("""
         import json, re
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.core import treeload
-        mesh = compat.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         from jax.sharding import NamedSharding, PartitionSpec as P
         x = jnp.zeros((8, 4, 4))
         xs = jax.device_put(x, NamedSharding(mesh, P("data")))
@@ -70,9 +70,9 @@ def test_checkpoint_restore_with_tree_broadcast(tmp_path):
     res = _run(f"""
         import json
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.checkpoint import save_checkpoint, load_checkpoint
-        mesh = compat.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         tree = {{"a": jnp.arange(12.0).reshape(3, 4), "b": {{"c": jnp.ones(5)}}}}
         save_checkpoint("{tmp_path}", 7, tree)
         like = jax.tree.map(lambda x: x, tree)
@@ -91,7 +91,6 @@ def test_moe_sharded_matches_single_device():
     res = _run("""
         import json
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.models import registry, moe
         from repro.sharding import make_rules, tree_shardings
         cfg = registry.get_config("olmoe-1b-7b", reduced=True)
@@ -106,8 +105,9 @@ def test_moe_sharded_matches_single_device():
              "w_down": jnp.asarray(rng.standard_normal((e, f, d)) * 0.1, jnp.float32)}
         # single-device reference
         ref, aux_ref = moe.apply_moe(cfg, p, x, rules)
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
-        with compat.set_mesh(mesh):
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        with jax.set_mesh(mesh):
             got, aux = jax.jit(lambda p, x: moe.apply_moe(cfg, p, x, rules))(p, x)
         # capacities differ (local T), so compare with loose tolerance on the
         # overlap: routing is identical, drops may differ near capacity
@@ -126,11 +126,12 @@ def test_elastic_reshard_preserves_values():
     res = _run("""
         import json
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.runtime import ElasticPlan, reshard_tree
         from repro.sharding import LogicalArray, make_rules
-        mesh_big = compat.make_mesh((2, 4), ("data", "model"))
-        mesh_small = compat.make_mesh((1, 4), ("data", "model"))
+        mesh_big = jax.make_mesh((2, 4), ("data", "model"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        mesh_small = jax.make_mesh((1, 4), ("data", "model"),
+                                   axis_types=(jax.sharding.AxisType.Auto,) * 2)
         abstract = {"w": LogicalArray((8, 16), jnp.float32, ("embed_fsdp", "ff"))}
         rules = make_rules(fsdp=True)
         from repro.sharding import tree_shardings

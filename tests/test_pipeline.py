@@ -21,10 +21,10 @@ def test_pipeline_forward_matches_sequential():
     code = """
         import json
         import jax, jax.numpy as jnp, numpy as np
-        from repro import compat
         from repro.runtime.pipeline import pipeline_forward
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = compat.make_mesh((4,), ("pod",))
+        mesh = jax.make_mesh((4,), ("pod",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         rng = np.random.default_rng(0)
         S, M, B, D = 4, 6, 2, 16
         # each stage: x -> tanh(x @ w + b)

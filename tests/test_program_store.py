@@ -77,6 +77,7 @@ def test_warm_boot_loads_instead_of_compiling(tmp_path):
     assert rep["source"] == "compile" and rep["compile_s"] > 0
     if cold.store.puts == 0:
         pytest.skip("executable serialization unavailable on this jax")
+    assert rep["store_s"] > 0
 
     # a rebooted process: fresh store object over the same directory
     warm = Syscore(store=ProgramStore(tmp_path))
@@ -84,6 +85,7 @@ def test_warm_boot_loads_instead_of_compiling(tmp_path):
     rep = warm.report()["programs"]["toy"]
     assert rep["source"] == "store"
     assert rep["load_s"] > 0 and rep["compile_s"] == 0
+    assert rep["store_s"] == 0
     assert rep["serialized_bytes"] > 0
     np.testing.assert_array_equal(np.asarray(toy2.block(w, x)), want)
     assert warm.store.hits == 1
@@ -108,6 +110,7 @@ def test_store_miss_on_corrupt_payload_falls_back_to_compile(tmp_path):
     w, x = _args()
     assert np.isfinite(np.asarray(toy.block(w, x))).all()
     assert warm.store.misses >= 1
+    assert warm.store.errors == 1          # the fallback is visible
 
 
 def test_store_keyed_on_environment_version(tmp_path, monkeypatch):
@@ -125,6 +128,7 @@ def test_store_keyed_on_environment_version(tmp_path, monkeypatch):
         skewed, "_env_key", lambda: ("jax-999.0", "jaxlib-999.0", "cpu", "1"))
     assert skewed.get(spec) is None
     assert skewed.misses == 1
+    assert skewed.errors == 0              # a skewed key is a plain miss
     warm = Syscore(store=skewed)
     warm.hot_load(spec)
     assert warm.report()["programs"]["toy"]["source"] == "compile"
@@ -148,6 +152,7 @@ def test_unserializable_program_is_skipped_not_fatal(tmp_path):
     out = np.asarray(prog.block(w, x))
     assert np.isfinite(out).all()
     assert store.skipped == 1 and store.puts == 0
+    assert store.errors == 1
     assert hct.metrics[0]                       # the callback still fired
 
 

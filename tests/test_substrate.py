@@ -239,10 +239,10 @@ def test_hlo_analyzer_collectives_scale_with_loop(tmp_path):
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, json
-        from repro import compat
         from jax.sharding import PartitionSpec as P
         from repro.launch import hlo_analysis as ha
-        mesh = compat.make_mesh((4,), ("model",))
+        mesh = jax.make_mesh((4,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         def step(ws, x):
             def body(x, w):
                 y = x @ w
@@ -250,7 +250,7 @@ def test_hlo_analyzer_collectives_scale_with_loop(tmp_path):
                 return y, None
             out, _ = jax.lax.scan(body, x, ws)
             return out
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             NS = lambda *spec: jax.sharding.NamedSharding(mesh, P(*spec))
             f = jax.jit(step, in_shardings=(NS(None, None, "model"),
                                             NS(None, "model")),
