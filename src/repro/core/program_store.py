@@ -183,9 +183,7 @@ class ProgramHandle:
 
     def __call__(self, *args):
         prog = self._syscore.lookup(self.key)
-        t0 = time.perf_counter()
         out = prog.compiled(*args)
-        prog.stats.last_exec_s = time.perf_counter() - t0
         prog.stats.executions += 1
         return out
 

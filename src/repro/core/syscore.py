@@ -63,7 +63,6 @@ class ProgramStats:
     load_s: float = 0.0            # hot-load (deserialize/install) time
     store_s: float = 0.0           # serialize + write to the program store
     executions: int = 0
-    last_exec_s: float = 0.0
     serialized_bytes: int = 0
 
 

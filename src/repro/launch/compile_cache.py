@@ -18,7 +18,13 @@ REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def use_compile_cache() -> str:
-    """Point the persistent compilation cache at its directory; returns it."""
+    """Point the persistent compilation cache at its directory; returns it.
+
+    The cache key covers each operation's metadata (its ``jax.named_scope``
+    path and source line), so an executable read back from the cache names
+    its operations as its own program does in a profile, not as another
+    program that differs from it only there."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -111,6 +111,15 @@ METRIC_HORIZON_TOKENS = 9  # tokens emitted per fused decode-horizon dispatch
 METRIC_PREFIX_HIT = 10    # prompt tokens served from shared prefix blocks
                           # (value = matched tokens), per warm admission
 
+# Host spans on the profiler's own clock, the one the device's events are
+# on: ``engine.step`` around a whole step, and inside it ``engine.admit``,
+# ``engine.pager`` (each call into the pager), ``engine.dispatch.<program>``
+# (the enqueue), ``engine.wait.<program>`` (blocking on that dispatch's
+# result), ``engine.place``, ``engine.emit`` and ``engine.telemetry``.  A
+# span is a TraceMe: about a microsecond, and nothing recorded, when no
+# profiler session is active.
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclass
 class Request:
@@ -398,33 +407,36 @@ class ServingEngine:
 
     def _place(self, slot: int, req: Request, last_logits: np.ndarray):
         """Post-prefill bookkeeping shared by both admission paths."""
-        first = int(np.argmax(last_logits[: self.cfg.vocab_size]))
-        req.generated.append(first)
-        if self.spec_k is not None:
-            # per-slot proposer state: one prompt-lookup index per request,
-            # created at first admission, fed as tokens append, surviving
-            # preempt/resume round trips (keyed by rid, not slot)
-            prop = self._proposers[req.rid] = NGramProposer(self.spec_ngram)
-            prop.observe(req.prompt.tolist())
-            prop.observe([first])
-        req.t_first = time.perf_counter()
-        req.slot = slot
-        req.gen_at_admit = len(req.generated)
-        self.slots[slot] = req
-        self.admitted += 1
-        # a refill = admission into a batch that is already mid-flight:
-        # some other slot's request has decoded past its prefill token and
-        # is still going.  Wave admissions (fresh batch, whether at boot or
-        # after a full drain) don't count — those are the seed engine's
-        # drain-then-refill schedule.
-        if any(s is not None and s is not req and len(s.generated) > 1
-               for s in self.slots):
-            self.refill_admissions += 1
-        self.syscore.hostcalls.dispatch(
-            CALL_METRIC, METRIC_TTFT_MS, 1e3 * req.ttft_s)
-        if self.trace is not None:
-            self.trace.on_admit(req)
-        self._maybe_finish(req)   # max_new == 1 or instant EOS
+        with _span("engine.place", rid=req.rid):
+            first = int(np.argmax(last_logits[: self.cfg.vocab_size]))
+            req.generated.append(first)
+            if self.spec_k is not None:
+                # per-slot proposer state: one prompt-lookup index per
+                # request, created at first admission, fed as tokens append,
+                # surviving preempt/resume round trips (keyed by rid, not
+                # slot)
+                prop = self._proposers[req.rid] = NGramProposer(
+                    self.spec_ngram)
+                prop.observe(req.prompt.tolist())
+                prop.observe([first])
+            req.t_first = time.perf_counter()
+            req.slot = slot
+            req.gen_at_admit = len(req.generated)
+            self.slots[slot] = req
+            self.admitted += 1
+            # a refill = admission into a batch that is already mid-flight:
+            # some other slot's request has decoded past its prefill token
+            # and is still going.  Wave admissions (fresh batch, whether at
+            # boot or after a full drain) don't count — those are the seed
+            # engine's drain-then-refill schedule.
+            if any(s is not None and s is not req and len(s.generated) > 1
+                   for s in self.slots):
+                self.refill_admissions += 1
+            self.syscore.hostcalls.dispatch(
+                CALL_METRIC, METRIC_TTFT_MS, 1e3 * req.ttft_s)
+            if self.trace is not None:
+                self.trace.on_admit(req)
+            self._maybe_finish(req)   # max_new == 1 or instant EOS
 
     def _pin_caches(self):
         """Re-pin the cache tree to its compiled program shardings before a
@@ -443,11 +455,15 @@ class ServingEngine:
         tokens = np.zeros((1, self.prefill_len), np.int32)
         tokens[0, :req.prompt_len] = req.prompt
         t1 = time.perf_counter()
-        self.caches, last = self._prefill_slot(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(slot, jnp.int32),
-            jnp.asarray(req.prompt_len, jnp.int32))
-        last = np.asarray(last)            # blocks on the device result
+        with _span("engine.dispatch.prefill_slot", rid=req.rid,
+                   prompt_len=req.prompt_len):
+            self.caches, last = self._prefill_slot(
+                self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(req.prompt_len, jnp.int32))
+        with _span("engine.wait.prefill_slot", rid=req.rid,
+                   prompt_len=req.prompt_len):
+            last = np.asarray(last)        # blocks on the device result
         if self.trace is not None:
             self.trace.on_dispatch("prefill_slot",
                                    time.perf_counter() - t1, active=1,
@@ -468,11 +484,15 @@ class ServingEngine:
         tokens = np.zeros((1, self.prefix_suffix), np.int32)
         tokens[0, :len(suffix)] = suffix
         t1 = time.perf_counter()
-        self.caches, last = self._prefill_offset(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(slot, jnp.int32), jnp.asarray(offset, jnp.int32),
-            jnp.asarray(req.prompt_len, jnp.int32))
-        last = np.asarray(last)            # blocks on the device result
+        with _span("engine.dispatch.prefill_offset", rid=req.rid,
+                   prompt_len=req.prompt_len):
+            self.caches, last = self._prefill_offset(
+                self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(slot, jnp.int32), jnp.asarray(offset, jnp.int32),
+                jnp.asarray(req.prompt_len, jnp.int32))
+        with _span("engine.wait.prefill_offset", rid=req.rid,
+                   prompt_len=req.prompt_len):
+            last = np.asarray(last)        # blocks on the device result
         if self.trace is not None:
             self.trace.on_dispatch("prefill_offset",
                                    time.perf_counter() - t1, active=1,
@@ -490,10 +510,12 @@ class ServingEngine:
             tokens[i, :req.prompt_len] = req.prompt
             lengths[i] = req.prompt_len
         t1 = time.perf_counter()
-        self.caches, last = self._prefill(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(lengths))
-        last = np.asarray(last)
+        with _span("engine.dispatch.prefill", active=len(reqs)):
+            self.caches, last = self._prefill(
+                self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(lengths))
+        with _span("engine.wait.prefill", active=len(reqs)):
+            last = np.asarray(last)
         if self.trace is not None:
             self.trace.on_dispatch("prefill", time.perf_counter() - t1,
                                    active=len(reqs), tokens=0)
@@ -502,23 +524,24 @@ class ServingEngine:
 
     def _admit(self):
         """Refill free slots from the queue, earliest arrival first."""
-        t = self.now()
-        if self.paged:
-            self._admit_paged(t)
-            return
-        eligible = sum(1 for r in self.queue if r.arrival_time <= t)
-        if (self.group_prefill and eligible >= 2
-                and not any(s is not None for s in self.slots)):
-            burst = [self.queue.pop(0)
-                     for _ in range(min(eligible, self.batch))]
-            self._admit_burst(burst)
-            return
-        for i, s in enumerate(self.slots):
-            if s is not None:
-                continue
-            if not self.queue or self.queue[0].arrival_time > t:
-                break
-            self._admit_one(i, self.queue.pop(0))
+        with _span("engine.admit"):
+            t = self.now()
+            if self.paged:
+                self._admit_paged(t)
+                return
+            eligible = sum(1 for r in self.queue if r.arrival_time <= t)
+            if (self.group_prefill and eligible >= 2
+                    and not any(s is not None for s in self.slots)):
+                burst = [self.queue.pop(0)
+                         for _ in range(min(eligible, self.batch))]
+                self._admit_burst(burst)
+                return
+            for i, s in enumerate(self.slots):
+                if s is not None:
+                    continue
+                if not self.queue or self.queue[0].arrival_time > t:
+                    break
+                self._admit_one(i, self.queue.pop(0))
 
     # -- paged admission / preemption -----------------------------------------
     def _blocks_needed(self, prompt_len: int, max_new: int) -> int:
@@ -536,14 +559,19 @@ class ServingEngine:
                 break
             req = self.queue[0]
             n_blocks = self._blocks_needed(req.prompt_len, req.max_new)
-            shared = (self.pager.match_prefix(req.prompt)
-                      if self.prefix_cfg is not None and not req.needs_resume
-                      else [])
-            if not self.pager.can_admit(req.rid, n_blocks, shared=shared):
+            shared = []
+            if self.prefix_cfg is not None and not req.needs_resume:
+                with _span("engine.pager", rid=req.rid):
+                    shared = self.pager.match_prefix(req.prompt)
+            with _span("engine.pager", rid=req.rid):
+                fits = self.pager.can_admit(req.rid, n_blocks, shared=shared)
+            if not fits:
                 if self.timeslice is not None:
                     self._preempt_expired()
-                if not self.pager.can_admit(req.rid, n_blocks,
-                                            shared=shared):
+                with _span("engine.pager", rid=req.rid):
+                    fits = self.pager.can_admit(req.rid, n_blocks,
+                                                shared=shared)
+                if not fits:
                     break
             # remove by identity: _preempt_expired may have re-queued a
             # victim AHEAD of the peeked head (same arrival time, smaller
@@ -556,8 +584,9 @@ class ServingEngine:
             if req.needs_resume:
                 self._resume_one(i, req)
             else:
-                self.caches = self.pager.admit(req.rid, n_blocks, i,
-                                               self.caches, shared=shared)
+                with _span("engine.pager", rid=req.rid):
+                    self.caches = self.pager.admit(req.rid, n_blocks, i,
+                                                   self.caches, shared=shared)
                 matched = len(shared) * self.kv_block
                 warm = (shared and self._prefix_tier1
                         and len(shared) >= self.prefix_cfg.min_blocks
@@ -580,8 +609,9 @@ class ServingEngine:
                 # blocks went back to the free list with it).
                 if self.prefix_cfg is not None and not warm \
                         and req.rid in self.pager.pages:
-                    self.caches = self.pager.publish(req.rid, req.prompt,
-                                                     i, self.caches)
+                    with _span("engine.pager", rid=req.rid):
+                        self.caches = self.pager.publish(req.rid, req.prompt,
+                                                         i, self.caches)
 
     def _resume_one(self, slot: int, req: Request):
         """Swap a preempted request back into a slot: the pager restores
@@ -589,7 +619,8 @@ class ServingEngine:
         written back to host) and its recurrent rows; decode then resumes
         from the exact position it left off, so the token stream is
         unchanged by the round trip."""
-        self.caches = self.pager.resume(req.rid, slot, self.caches)
+        with _span("engine.pager", rid=req.rid):
+            self.caches = self.pager.resume(req.rid, slot, self.caches)
         self.caches["pos"] = self.caches["pos"].at[slot].set(
             req.prompt_len + len(req.generated) - 1)
         req.slot = slot
@@ -606,7 +637,8 @@ class ServingEngine:
         the request behind current waiters (round-robin rotation); the
         default keeps its original arrival time (resume ASAP)."""
         assert self.paged and req.slot >= 0 and not req.done
-        self.caches = self.pager.preempt(req.rid, req.slot, self.caches)
+        with _span("engine.pager", rid=req.rid):
+            self.caches = self.pager.preempt(req.rid, req.slot, self.caches)
         self.slots[req.slot] = None
         req.slot = -1
         req.needs_resume = True
@@ -641,8 +673,9 @@ class ServingEngine:
                 # release() handles that case without touching any live
                 # block-table row, freeing resident blocks exactly once and
                 # dropping the host-tier kvpage: entries
-                self.caches = self.pager.release(req.rid, req.slot,
-                                                 self.caches)
+                with _span("engine.pager", rid=req.rid):
+                    self.caches = self.pager.release(req.rid, req.slot,
+                                                     self.caches)
             if req.slot >= 0:
                 self.slots[req.slot] = None
 
@@ -654,18 +687,19 @@ class ServingEngine:
         occupancy, optional gauges and the step report — stamped with the
         monotonic host clock so a recorded trace replays with real
         inter-dispatch gaps."""
-        calls = [(CALL_METRIC, METRIC_DECODE_MS, 1e3 * dt),
-                 (CALL_METRIC, METRIC_OCCUPANCY, occupancy)]
-        calls.extend(extra)
-        if self.paged:
-            calls.append((CALL_METRIC, METRIC_ARENA_OCCUPANCY,
-                          self.pager.arena_occupancy()))
-        calls.append((CALL_STEP_REPORT, self.decode_steps, dt,
-                      time.perf_counter()))
-        self.syscore.hostcalls.dispatch(CALL_BATCH, calls)
-        if self.trace is not None:
-            self.trace.on_dispatch(program, dt, active=active,
-                                   tokens=tokens, **(trace_extra or {}))
+        with _span("engine.telemetry"):
+            calls = [(CALL_METRIC, METRIC_DECODE_MS, 1e3 * dt),
+                     (CALL_METRIC, METRIC_OCCUPANCY, occupancy)]
+            calls.extend(extra)
+            if self.paged:
+                calls.append((CALL_METRIC, METRIC_ARENA_OCCUPANCY,
+                              self.pager.arena_occupancy()))
+            calls.append((CALL_STEP_REPORT, self.decode_steps, dt,
+                          time.perf_counter()))
+            self.syscore.hostcalls.dispatch(CALL_BATCH, calls)
+            if self.trace is not None:
+                self.trace.on_dispatch(program, dt, active=active,
+                                       tokens=tokens, **(trace_extra or {}))
 
     def _decode_once(self):
         self._pin_caches()
@@ -675,21 +709,24 @@ class ServingEngine:
                 tokens[i, 0] = req.generated[-1]
         active = sum(s is not None for s in self.slots)
         t1 = time.perf_counter()
-        self.caches, next_tok, _ = self._decode(
-            self.params, self.caches, jnp.asarray(tokens))
-        nt = np.asarray(next_tok)           # blocks on the device result
+        with _span("engine.dispatch.decode", active=active):
+            self.caches, next_tok, _ = self._decode(
+                self.params, self.caches, jnp.asarray(tokens))
+        with _span("engine.wait.decode", active=active):
+            nt = np.asarray(next_tok)       # blocks on the device result
         dt = time.perf_counter() - t1
         self.decode_steps += 1
         self.decode_tokens += active
         self._step_metrics(dt, active / self.batch, program="decode",
                            active=active, tokens=active)
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            req.generated.append(int(nt[i, 0]))
-            if self.spec_k is not None and req.rid in self._proposers:
-                self._proposers[req.rid].observe(req.generated[-1:])
-            self._maybe_finish(req)
+        with _span("engine.emit"):
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                req.generated.append(int(nt[i, 0]))
+                if self.spec_k is not None and req.rid in self._proposers:
+                    self._proposers[req.rid].observe(req.generated[-1:])
+                self._maybe_finish(req)
         return dt
 
     def _verify_once(self):
@@ -728,36 +765,45 @@ class ServingEngine:
                 pos0 = req.prompt_len + len(req.generated) - 1
                 need = min(-(-(pos0 + k + 1) // self.kv_block),
                            self.blocks_per_slot)
-                self.caches = self.pager.grow(req.rid, need, i, self.caches)
+                with _span("engine.pager", rid=req.rid):
+                    self.caches = self.pager.grow(req.rid, need, i,
+                                                  self.caches)
         self._pin_caches()
         t1 = time.perf_counter()
-        self.caches, ys, n_new = self._verify(
-            self.params, self.caches, jnp.asarray(tokens))
-        ys = np.asarray(ys)
-        n_new = np.asarray(n_new)          # blocks on the device result
+        with _span("engine.dispatch.verify", active=active):
+            self.caches, ys, n_new = self._verify(
+                self.params, self.caches, jnp.asarray(tokens))
+        with _span("engine.wait.verify", active=active):
+            ys = np.asarray(ys)
+            n_new = np.asarray(n_new)      # blocks on the device result
         dt = time.perf_counter() - t1
         self.decode_steps += 1
         self.spec_steps += 1
         accepted = 0
         toks0 = self.decode_tokens
-        for i, req in enumerate(list(self.slots)):
-            if req is None:
-                continue
-            used = 0
-            for j in range(int(n_new[i])):
-                if req.done:
-                    break                  # EOS / budget hit mid-accept
-                req.generated.append(int(ys[i, j]))
-                used += 1
-                self._maybe_finish(req)
-            self.decode_tokens += used
-            accepted += min(used - 1, int(n_props[i]))
-            if req.rid in self._proposers:
-                self._proposers[req.rid].observe(req.generated[-used:])
-            if self.paged and req.rid in self.pager.pages and req.slot >= 0:
-                # reclaim on rejection: speculative tail blocks go back to
-                # the free list (verify restored their bytes in-program)
-                self.caches = self.pager.trim_to_base(req.rid, i, self.caches)
+        with _span("engine.emit"):
+            for i, req in enumerate(list(self.slots)):
+                if req is None:
+                    continue
+                used = 0
+                for j in range(int(n_new[i])):
+                    if req.done:
+                        break              # EOS / budget hit mid-accept
+                    req.generated.append(int(ys[i, j]))
+                    used += 1
+                    self._maybe_finish(req)
+                self.decode_tokens += used
+                accepted += min(used - 1, int(n_props[i]))
+                if req.rid in self._proposers:
+                    self._proposers[req.rid].observe(req.generated[-used:])
+                if (self.paged and req.rid in self.pager.pages
+                        and req.slot >= 0):
+                    # reclaim on rejection: speculative tail blocks go back
+                    # to the free list (verify restored their bytes
+                    # in-program)
+                    with _span("engine.pager", rid=req.rid):
+                        self.caches = self.pager.trim_to_base(req.rid, i,
+                                                              self.caches)
         self.draft_tokens += drafted
         self.accepted_drafts += accepted
         self._step_metrics(dt, active / self.batch,
@@ -828,27 +874,30 @@ class ServingEngine:
             budget[i] = min(self._budget_left(req), self.horizon)
         active = sum(s is not None for s in self.slots)
         t1 = time.perf_counter()
-        self.caches, events = self._decode_horizon(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(budget))
-        toks = np.asarray(events["tokens"])      # blocks on the device result
-        n_emit = np.asarray(events["n_emitted"])
-        occ = np.asarray(events["occupancy"])
+        with _span("engine.dispatch.decode_horizon", active=active):
+            self.caches, events = self._decode_horizon(
+                self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(budget))
+        with _span("engine.wait.decode_horizon", active=active):
+            toks = np.asarray(events["tokens"])  # blocks on the device result
+            n_emit = np.asarray(events["n_emitted"])
+            occ = np.asarray(events["occupancy"])
         dt = time.perf_counter() - t1
         emitted = int(n_emit.sum())
         self.decode_steps += 1
         self.horizon_steps += 1
         self.decode_tokens += emitted
         self.horizon_tokens += emitted
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            new = [int(t) for t in toks[i, :n_emit[i]]]
-            req.generated.extend(new)
-            if new and self.spec_k is not None and \
-                    req.rid in self._proposers:
-                self._proposers[req.rid].observe(new)
-            self._maybe_finish(req)
+        with _span("engine.emit"):
+            for i, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                new = [int(t) for t in toks[i, :n_emit[i]]]
+                req.generated.extend(new)
+                if new and self.spec_k is not None and \
+                        req.rid in self._proposers:
+                    self._proposers[req.rid].observe(new)
+                self._maybe_finish(req)
         # one METRIC_OCCUPANCY entry per *executed* in-graph step (steps
         # after every row froze are skipped), so the channel keeps its
         # per-decode-step weighting: a horizon covering 15 tokens
@@ -932,19 +981,20 @@ class ServingEngine:
         remains."""
         if not self.has_work:
             return False
-        self._admit()
-        if any(s is not None for s in self.slots):
-            if self.spec_k is not None:
-                self._verify_once()
-            else:
-                self._advance_decode()
-        elif self.clock == "wall" and self.queue:
-            # idle: sleep toward the earliest future arrival (capped so a
-            # far-future request costs O(wait/10ms) engine ticks, not a
-            # 10 kHz busy-poll that drains run()'s step budget)
-            wait = self.queue[0].arrival_time - self.now()
-            time.sleep(min(max(wait, 1e-4), 1e-2))
-        self.steps += 1
+        with _span("engine.step"):
+            self._admit()
+            if any(s is not None for s in self.slots):
+                if self.spec_k is not None:
+                    self._verify_once()
+                else:
+                    self._advance_decode()
+            elif self.clock == "wall" and self.queue:
+                # idle: sleep toward the earliest future arrival (capped so
+                # a far-future request costs O(wait/10ms) engine ticks, not
+                # a 10 kHz busy-poll that drains run()'s step budget)
+                wait = self.queue[0].arrival_time - self.now()
+                time.sleep(min(max(wait, 1e-4), 1e-2))
+            self.steps += 1
         return True
 
     def run(self, max_steps: int = 10_000) -> Dict[str, float]:

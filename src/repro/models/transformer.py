@@ -200,14 +200,19 @@ def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
             # data-page jump table of repro.core.paging).  The paged path
             # serves the single-host tier, so it keeps the simple
             # full-repeat attention (no head_dim-sharded GQA variant).
-            k_arena = attn_mod.write_paged_kv(cache["k"], block_table,
-                                              pos_b, k[:, 0], live=live)
-            v_arena = attn_mod.write_paged_kv(cache["v"], block_table,
-                                              pos_b, v[:, 0], live=live)
-            k_log = attn_mod.gather_paged_kv(k_arena, block_table)
-            v_log = attn_mod.gather_paged_kv(v_arena, block_table)
-            out = attn_mod.decode_attention(
-                q, k_log, v_log, pos_b + 1, window=window, ring=False)
+            # The scopes name the paged-KV work in each operation's
+            # metadata, so a device trace can charge it to this layer.
+            with jax.named_scope("paged_kv/write"):
+                k_arena = attn_mod.write_paged_kv(cache["k"], block_table,
+                                                  pos_b, k[:, 0], live=live)
+                v_arena = attn_mod.write_paged_kv(cache["v"], block_table,
+                                                  pos_b, v[:, 0], live=live)
+            with jax.named_scope("paged_kv/gather"):
+                k_log = attn_mod.gather_paged_kv(k_arena, block_table)
+                v_log = attn_mod.gather_paged_kv(v_arena, block_table)
+            with jax.named_scope("paged_kv/attend"):
+                out = attn_mod.decode_attention(
+                    q, k_log, v_log, pos_b + 1, window=window, ring=False)
             out = constrain(out, out_spec, rules)
             out = jnp.einsum("bsh,hd->bsd",
                              out.reshape(b, s, cfg.n_heads * hd), p["wo"])
@@ -345,7 +350,8 @@ def apply_layer(cfg, kind: str, p: Params, x, *, rules, mode, cache, pos,
         if cfg.family == "moe":
             out, aux = moe_mod.apply_moe(cfg, p["moe"], xn, rules)
         else:
-            out = apply_mlp(p["mlp"], xn, rules)
+            with jax.named_scope("mlp"):
+                out = apply_mlp(p["mlp"], xn, rules)
         x = residual + out
     return x, new_cache, aux
 
@@ -527,6 +533,7 @@ def embed_inputs(cfg, params, tokens, prefix_embeds, rules):
     return constrain(x, ("batch", "seq", "embed"), rules)
 
 
+@jax.named_scope("logits")
 def logits_from_hidden(cfg, params, x, rules):
     x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if cfg.tie_embeddings:
@@ -559,6 +566,7 @@ def forward(cfg, params, tokens, *, rules, prefix_embeds=None, mode="train",
     return logits, new_caches, aux
 
 
+@jax.named_scope("sample")
 def greedy_token(cfg, logits):
     """THE greedy-decoding argmax, shared by every decode mode.
 

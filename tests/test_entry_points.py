@@ -13,6 +13,7 @@ import textwrap
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -113,6 +114,42 @@ def test_compile_cache_defaults_to_a_fixed_path_in_the_checkout(
         assert compile_cache.use_compile_cache() == first
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_a_cached_executable_keeps_its_own_scopes(monkeypatch, tmp_path):
+    """Two programs that differ only in a named scope: the second, compiled
+    while the first is in the persistent cache, names its operations by
+    its own scope, so a profile reads the program that ran."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_compilation_cache_include_metadata_in_key")
+    before = {k: getattr(jax.config, k) for k in keys}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
+    def scoped(name):
+        def f(x):
+            with jax.named_scope(name):
+                return jnp.sin(x) * 2
+        return jax.jit(f)
+
+    try:
+        compile_cache.use_compile_cache()
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        cc.reset_cache()
+        x = jax.ShapeDtypeStruct((8,), jnp.float32)
+        first = scoped("first").lower(x).compile().as_text()
+        assert any(tmp_path.iterdir())          # the first is cached
+        second = scoped("second").lower(x).compile().as_text()
+        assert "first" in first
+        assert "second" in second and "first" not in second
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
 
 
 @pytest.mark.parametrize("argv,reduced,max_len", [
