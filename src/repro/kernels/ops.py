@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import matmul as _mm
 from repro.kernels import moe_dispatch as _moe
+from repro.kernels import paged_attention as _pa
 from repro.kernels import ref
 from repro.kernels import rglru_scan as _rg
 from repro.kernels import ssd_scan as _ssd
@@ -83,3 +84,19 @@ def moe_ffn(buf, w1, w3, w2, *, impl: Optional[str] = None,
         return ref.moe_ffn(buf, w1, w3, w2)
     return _moe.moe_ffn(buf, w1, w3, w2, block_c=block_c,
                         interpret=(impl == "interpret"))
+
+
+@functools.partial(jax.jit, static_argnames=("impl", "blocks_per_wave"))
+def paged_decode_attention(q, k_arena, v_arena, block_table, lengths, *,
+                           impl: Optional[str] = None,
+                           blocks_per_wave: int = 8):
+    """q (B, H, D) against each row's live blocks of (P, bs, Hkv, D)
+    arenas through ``block_table`` (B, M); ``lengths`` (B,)."""
+    impl = _resolve(impl)
+    if impl == "xla":
+        return ref.paged_decode_attention(q, k_arena, v_arena, block_table,
+                                          lengths)
+    return _pa.paged_decode_attention(q, k_arena, v_arena, block_table,
+                                      lengths,
+                                      blocks_per_wave=blocks_per_wave,
+                                      interpret=(impl == "interpret"))

@@ -9,6 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.models import attention as attn
+
 
 def matmul(x: jax.Array, w: jax.Array) -> jax.Array:
     return jnp.dot(x.astype(jnp.float32),
@@ -83,3 +85,12 @@ def moe_ffn(buf, w1, w3, w2):
     h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, w1)) * jnp.einsum(
         "ecd,edf->ecf", buf, w3)
     return jnp.einsum("ecf,efd->ecd", h, w2)
+
+
+def paged_decode_attention(q, k_arena, v_arena, block_table, lengths):
+    """The XLA paged decode read: gather every row's whole block table out
+    of the arena, repeat it to the query heads, attend the first
+    ``lengths`` positions.  q: (B, H, D); arenas: (P, bs, Hkv, D)."""
+    k = attn.gather_paged_kv(k_arena, block_table)
+    v = attn.gather_paged_kv(v_arena, block_table)
+    return attn.decode_attention(q[:, None], k, v, lengths)[:, 0]

@@ -14,11 +14,14 @@ Modes: "train" (no cache), "prefill" (writes cache), "decode" (one token).
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+from repro.kernels import paged_attention as paged_attn
 from repro.models import attention as attn_mod
 from repro.models import hybrid as hybrid_mod
 from repro.models import moe as moe_mod
@@ -26,7 +29,8 @@ from repro.models import ssm as ssm_mod
 from repro.models.layers import (apply_embedding, apply_lm_head, apply_mlp,
                                  apply_rmsnorm, apply_rope, embedding_abstract,
                                  mlp_abstract, rmsnorm_abstract)
-from repro.sharding import LogicalArray, constrain
+from repro.sharding import (LogicalArray, constrain,
+                            get_abstract_mesh_or_none)
 
 Params = Dict[str, Any]
 
@@ -98,7 +102,6 @@ def _attn_cache_abstract(cfg, kind, batch, cache_len, ring=True) -> Params:
 def _decode_kv_spec(cfg):
     """Sharding for the repeated decode KV: heads when they divide the TP
     degree, else head_dim (never forces a cross-layout reshard of the cache)."""
-    from repro.sharding import get_abstract_mesh_or_none
     mesh = get_abstract_mesh_or_none()
     tp = 1
     if mesh is not None and not mesh.empty and "model" in mesh.axis_names:
@@ -109,6 +112,34 @@ def _decode_kv_spec(cfg):
     if cfg.resolved_head_dim % tp == 0:
         return ("batch", None, None, "heads")   # model axis on head_dim
     return ("batch", None, None, None)
+
+
+def _paged_attention_impl(rules, window: int) -> str:
+    """The paged decode read a layer takes: the Pallas kernel over live
+    blocks where the backend runs one (``ops.default_impl()``), the layer
+    attends its whole context (``window == 0``) and its KV heads are
+    unsharded (no mesh, or one device on the axis ``rules`` maps them to);
+    else the XLA gather of every slot's whole block table."""
+    impl = ops.default_impl()
+    if impl == "xla" or window:
+        return "xla"
+    mesh = get_abstract_mesh_or_none()
+    axes = rules.get("kv_heads")
+    if mesh is not None and axes is not None:
+        axes = axes if isinstance(axes, (tuple, list)) else (axes,)
+        if math.prod(mesh.shape.get(a, 1) for a in axes) > 1:
+            return "xla"
+    return impl
+
+
+def paged_attention_context() -> str:
+    """The paged decode read this backend selects, for the fingerprint
+    context of every program that decodes from a paged arena: the step's
+    source does not show model code, and a program store must never hand a
+    program built on one read to an engine built on the other."""
+    impl = ops.default_impl()
+    return repr(("paged_attention", impl,
+                 None if impl == "xla" else paged_attn.VERSION))
 
 
 def _write_prefill_cache(cache_kv, full, window: int, lengths=None):
@@ -196,23 +227,32 @@ def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
         if block_table is not None:
             # paged KV: the cache leaf is a (P, bs, ch, hd) physical-block
             # arena shared by every slot; this row's write destination and
-            # the logical gather both resolve through the block table (the
-            # data-page jump table of repro.core.paging).  The paged path
-            # serves the single-host tier, so it keeps the simple
-            # full-repeat attention (no head_dim-sharded GQA variant).
-            # The scopes name the paged-KV work in each operation's
-            # metadata, so a device trace can charge it to this layer.
+            # its reads both resolve through the block table (the
+            # data-page jump table of repro.core.paging).  The read is the
+            # Pallas kernel over each row's live blocks where
+            # _paged_attention_impl allows it, else the XLA gather of the
+            # whole table with the simple full-repeat attention (no
+            # head_dim-sharded GQA variant).  The scopes name the paged-KV
+            # work in each operation's metadata, so a device trace can
+            # charge it to this layer.
             with jax.named_scope("paged_kv/write"):
                 k_arena = attn_mod.write_paged_kv(cache["k"], block_table,
                                                   pos_b, k[:, 0], live=live)
                 v_arena = attn_mod.write_paged_kv(cache["v"], block_table,
                                                   pos_b, v[:, 0], live=live)
-            with jax.named_scope("paged_kv/gather"):
-                k_log = attn_mod.gather_paged_kv(k_arena, block_table)
-                v_log = attn_mod.gather_paged_kv(v_arena, block_table)
-            with jax.named_scope("paged_kv/attend"):
-                out = attn_mod.decode_attention(
-                    q, k_log, v_log, pos_b + 1, window=window, ring=False)
+            impl = _paged_attention_impl(rules, window)
+            if impl == "xla":
+                with jax.named_scope("paged_kv/gather"):
+                    k_log = attn_mod.gather_paged_kv(k_arena, block_table)
+                    v_log = attn_mod.gather_paged_kv(v_arena, block_table)
+                with jax.named_scope("paged_kv/attend"):
+                    out = attn_mod.decode_attention(
+                        q, k_log, v_log, pos_b + 1, window=window, ring=False)
+            else:
+                with jax.named_scope("paged_kv/attend"):
+                    out = ops.paged_decode_attention(
+                        q[:, 0], k_arena, v_arena, block_table, pos_b + 1,
+                        impl=impl)[:, None]
             out = constrain(out, out_spec, rules)
             out = jnp.einsum("bsh,hd->bsd",
                              out.reshape(b, s, cfg.n_heads * hd), p["wo"])

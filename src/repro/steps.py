@@ -488,6 +488,12 @@ def serve_program_specs(cfg, rules, config=None, *,
     out_logits = LogicalArray((batch, 1, V), jnp.float32,
                               ("batch", None, "vocab"))
     context = _spec_context(cfg, rules, config.program_context())
+    # the programs that decode from a paged arena also carry which paged
+    # read (Pallas kernel or XLA gather) the backend selects: it lives in
+    # model code, which the step's source does not show
+    decode_context = context
+    if paged:
+        decode_context += "|" + transformer.paged_attention_context()
 
     specs = {
         "prefill_slot": ProgramSpec(
@@ -503,7 +509,7 @@ def serve_program_specs(cfg, rules, config=None, *,
         "decode": ProgramSpec(
             key="decode", fn=make_serve_step(cfg, rules),
             abstract_args=(p_abstract, c_abstract, tok_decode),
-            donate_argnums=(1,), context=context,
+            donate_argnums=(1,), context=decode_context,
             out_logical=(c_abstract, out_tok, out_logits)),
     }
     if not paged:
@@ -541,7 +547,7 @@ def serve_program_specs(cfg, rules, config=None, *,
         specs["verify"] = ProgramSpec(
             key="verify", fn=make_verify_step(cfg, rules),
             abstract_args=(p_abstract, c_abstract, tok_verify),
-            donate_argnums=(1,), context=context,
+            donate_argnums=(1,), context=decode_context,
             out_logical=(c_abstract,
                          LogicalArray((batch, spec_k + 1), jnp.int32,
                                       ("batch", None)),
@@ -554,7 +560,7 @@ def serve_program_specs(cfg, rules, config=None, *,
             fn=make_decode_horizon_step(cfg, rules, H, config.eos_id),
             abstract_args=(p_abstract, c_abstract, tok_decode, budget),
             donate_argnums=(1,),
-            context=context + "|" + config.horizon_context(),
+            context=decode_context + "|" + config.horizon_context(),
             out_logical=(c_abstract, {
                 "tokens": LogicalArray((batch, H), jnp.int32,
                                        ("batch", None)),

@@ -15,7 +15,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import steps
-from repro.engine_config import EngineConfig
+from repro.engine_config import EngineConfig, PagingConfig
 from repro.kernels import ops
 from repro.models import registry
 from repro.sharding import make_rules, tree_structs
@@ -92,3 +92,22 @@ def test_flash_attention_kernel_compiles_for_v5e(one_chip):
     text = ops.flash_attention.lower(q, kv, kv,
                                      impl="pallas").compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_paged_decode_with_kernel_fits_one_v5e_chip(one_chip, monkeypatch):
+    """The paged ``decode`` program at the chip benchmark's engine size (32
+    slots, 2,048 positions, a 2,560-block arena of 16-token blocks), with
+    the paged read the TPU backend selects, compiles for one chip with the
+    Pallas kernel in it and fits the chip's HBM."""
+    monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
+    cfg = registry.get_config(ARCH, reduced=False)
+    config = EngineConfig(reduced=False, batch=32, max_len=2048,
+                          prefill_len=1024,
+                          paging=PagingConfig(kv_block=16, arena_blocks=2560))
+    spec = steps.serve_program_specs(cfg, make_rules(), config)["decode"]
+    args = _on(one_chip, tree_structs(spec.abstract_args))
+    compiled = jax.jit(spec.fn, donate_argnums=spec.donate_argnums).lower(
+        *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES, mem
+    assert "paged_decode_attention" in compiled.as_text()

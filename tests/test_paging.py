@@ -14,14 +14,21 @@ Three layers of coverage:
   * the system path: a warm boot from the program store into a paged
     serving run whose total KV footprint exceeds the arena.
 """
+import contextlib
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import PagedKVManager, ProgramStore
+from repro.kernels import ops
 from repro.launch.serve import (METRIC_ARENA_OCCUPANCY, METRIC_PAGE_FAULT,
                                 ServingEngine)
+from repro.models import registry, transformer
+from repro.sharding import make_rules
 
 
 # ---------------------------------------------------------------------------
@@ -288,3 +295,93 @@ def test_paged_warm_boot_from_store_token_exact(tmp_path):
     for c, w, p in zip(cold_reqs, warm_reqs, prompts):
         assert w.generated == c.generated
         assert w.generated == warm.reference_generate(p, 6)
+
+
+# ---------------------------------------------------------------------------
+# the paged decode read: Pallas kernel over live blocks vs the XLA gather
+# ---------------------------------------------------------------------------
+def _paged_decode_setup(cfg, batch=3, max_len=32, bs=4, arena_blocks=24):
+    """A paged cache mid-generation: random arena contents stand for the
+    prefilled context; row 0 maps every block through a shuffled table,
+    row 1 starts on two read-only shared blocks, row 2 is unmapped."""
+    rng = np.random.default_rng(0)
+    caches = transformer.init_paged_cache(cfg, batch, max_len, kv_block=bs,
+                                          arena_blocks=arena_blocks)
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+    for group in (caches["groups"], caches["tail"]):
+        for slot in group.values():
+            for name in ("k", "v"):
+                slot[name] = jax.random.normal(next(keys), slot[name].shape,
+                                               slot[name].dtype)
+    phys = rng.permutation(arena_blocks)
+    table = np.full((batch, max_len // bs), -1, np.int32)
+    table[0] = phys[:8]
+    table[1, :2] = -(phys[8:10] + 2)
+    table[1, 2:] = phys[10:16]
+    caches["block_table"] = jnp.asarray(table)
+    caches["pos"] = jnp.asarray([3, 9, 0], jnp.int32)
+    tokens = jnp.asarray([[5], [17], [0]], jnp.int32)
+    return caches, tokens
+
+
+def _decode_streams(cfg, params, impl, monkeypatch, steps=16):
+    """Greedy tokens and logits of ``steps`` paged ``decode_step`` calls,
+    and the tokens of one ``decode_horizon`` over the same steps, with the
+    paged read that ``impl`` selects."""
+    monkeypatch.setattr(ops, "default_impl", lambda: impl)
+    rules = make_rules()
+    caches, tok = _paged_decode_setup(cfg)
+    step = jax.jit(functools.partial(transformer.decode_step, cfg,
+                                     rules=rules))
+    horizon = jax.jit(functools.partial(transformer.decode_horizon, cfg,
+                                        rules=rules, horizon=steps))
+    _, events = horizon(params, caches, tok,
+                        jnp.asarray([steps, steps, 0], jnp.int32))
+    toks, logits = [], []
+    for _ in range(steps):
+        out, caches = step(params, caches, tok)
+        tok = transformer.greedy_token(cfg, out)
+        toks.append(np.asarray(tok[:2, 0]))
+        logits.append(np.asarray(out[:2, 0]))
+    return (np.stack(toks, 1), np.stack(logits, 1),
+            np.asarray(events["tokens"][:2]))
+
+
+def test_paged_kernel_decode_matches_xla_path(monkeypatch):
+    """Paged ``decode_step`` and ``decode_horizon`` over 16 steps that
+    cross block boundaries give the same greedy tokens with the Pallas
+    kernel (interpreted) as with the XLA gather, and logits within
+    tolerance; the horizon equals the single steps on both paths."""
+    cfg = registry.get_config("qwen3-0.6b", reduced=True)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    k_toks, k_logits, k_horizon = _decode_streams(cfg, params, "interpret",
+                                                  monkeypatch)
+    x_toks, x_logits, x_horizon = _decode_streams(cfg, params, "xla",
+                                                  monkeypatch)
+    np.testing.assert_array_equal(k_toks, x_toks)
+    np.testing.assert_allclose(k_logits, x_logits, rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(k_horizon, k_toks)
+    np.testing.assert_array_equal(x_horizon, x_toks)
+
+
+@pytest.mark.parametrize("case", ["kernel", "window", "sharded_kv_heads"])
+def test_paged_kernel_selection(monkeypatch, case):
+    """The paged decode program holds the kernel only where the layer
+    attends its whole context and its KV heads are unsharded: a
+    local-window layer and KV heads split over a mesh keep the XLA
+    gather."""
+    monkeypatch.setattr(ops, "default_impl", lambda: "interpret")
+    cfg = registry.get_config("qwen3-0.6b", reduced=True)
+    mesh = contextlib.nullcontext()
+    if case == "window":
+        cfg = cfg.replace(layer_pattern=("L",), local_window=8)
+    elif case == "sharded_kv_heads":
+        mesh = jax.sharding.use_abstract_mesh(
+            jax.sharding.AbstractMesh((2,), ("model",)))
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
+    caches, tok = _paged_decode_setup(cfg)
+    step = functools.partial(transformer.decode_step, cfg, rules=make_rules())
+    with mesh:
+        text = str(jax.make_jaxpr(step)(params, caches, tok))
+    assert ("paged_decode_attention" in text) == (case == "kernel")

@@ -63,6 +63,35 @@ def test_fingerprint_covers_donation():
     assert a.fingerprint != b.fingerprint
 
 
+def test_paged_decode_fingerprints_name_the_paged_read(monkeypatch):
+    """The paged programs that decode carry the paged read in their
+    fingerprint: the Pallas kernel and the XLA gather never share a store
+    entry, and two builds of the same read agree."""
+    from repro import steps
+    from repro.engine_config import (EngineConfig, HorizonConfig,
+                                     PagingConfig, SpecConfig)
+    from repro.kernels import ops
+    from repro.models import registry
+    from repro.sharding import make_rules
+    cfg = registry.get_config("qwen3-0.6b", reduced=True)
+    config = EngineConfig(batch=2, max_len=32,
+                          paging=PagingConfig(kv_block=4),
+                          spec=SpecConfig(k=2), horizon=HorizonConfig(4))
+    decoding = ("decode", "decode_horizon", "verify")
+
+    def fingerprints(impl):
+        monkeypatch.setattr(ops, "default_impl", lambda: impl)
+        specs = steps.serve_program_specs(cfg, make_rules(), config)
+        return {k: specs[k].fingerprint for k in decoding + ("prefill_slot",)}
+
+    kernel, xla = fingerprints("pallas"), fingerprints("xla")
+    assert fingerprints("pallas") == kernel
+    assert fingerprints("xla") == xla
+    for key in decoding:
+        assert kernel[key] != xla[key], key
+    assert kernel["prefill_slot"] == xla["prefill_slot"]
+
+
 # ---------------------------------------------------------------------------
 # Store-backed warm boot
 # ---------------------------------------------------------------------------
