@@ -41,8 +41,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 # Folded into the serving programs' fingerprint context: bump it with any
-# change to what the kernel computes or how it is scheduled.
-VERSION = "paged_decode_attention/1"
+# change to what the kernel computes or how it is scheduled (2: it also
+# runs once per shard of the KV heads where a mesh splits them).
+VERSION = "paged_decode_attention/2"
 
 
 def _live_lengths(block_table: jax.Array, lengths: jax.Array,

@@ -77,6 +77,7 @@ from __future__ import annotations
 
 import argparse
 import bisect
+import functools
 import time
 import warnings
 from dataclasses import dataclass, field
@@ -296,12 +297,17 @@ class ServingEngine:
         self._verify = self.programs.get("verify")
         self._decode_horizon = self.programs.get("decode_horizon")
 
+        self._cache_shardings = None
+        if self.mesh is not None:
+            c_abstract = specs["decode"].abstract_args[1]
+            self._cache_shardings = tree_shardings(c_abstract, self.rules,
+                                                   self.mesh)
         if self.paged:
             from repro.core.paging import (PagedKVManager, PrefixStore,
                                            leaf_kind)
-            self.caches = transformer.init_paged_cache(
-                cfg, self.batch, self.max_len, kv_block=self.kv_block,
-                arena_blocks=self.arena_blocks)
+            self.caches = self._new_caches(functools.partial(
+                transformer.init_paged_cache, cfg, self.batch, self.max_len,
+                kv_block=self.kv_block, arena_blocks=self.arena_blocks))
             if self.prefix_cfg is not None and prefix_store is None:
                 # engine-private store; a cluster supervisor passes ONE
                 # shared PrefixStore so prefixes survive replica failover
@@ -331,15 +337,9 @@ class ServingEngine:
                 self._prefix_tier1 = ("kv" in kinds and "state" not in kinds
                                       and self.cfg.n_experts == 0)
         else:
-            self.caches = transformer.init_cache(cfg, self.batch,
-                                                 self.max_len,
-                                                 ring=self.spec_k is None)
-        self._cache_shardings = None
-        if self.mesh is not None:
-            c_abstract = specs["decode"].abstract_args[1]
-            self._cache_shardings = tree_shardings(c_abstract, self.rules,
-                                                   self.mesh)
-            self.caches = jax.device_put(self.caches, self._cache_shardings)
+            self.caches = self._new_caches(functools.partial(
+                transformer.init_cache, cfg, self.batch, self.max_len,
+                ring=self.spec_k is None))
         self._proposers: Dict[int, NGramProposer] = {}
         self.spec_steps = 0            # verify-program executions
         self.draft_tokens = 0          # drafts proposed (engine lifetime)
@@ -437,6 +437,14 @@ class ServingEngine:
             if self.trace is not None:
                 self.trace.on_admit(req)
             self._maybe_finish(req)   # max_new == 1 or instant EOS
+
+    def _new_caches(self, init):
+        """The cache tree ``init()`` makes, where it lives: on a mesh each
+        device makes only its own shards (the whole tree of a model spread
+        over several chips need not fit one of them)."""
+        if self._cache_shardings is None:
+            return init()
+        return jax.jit(init, out_shardings=self._cache_shardings)()
 
     def _pin_caches(self):
         """Re-pin the cache tree to its compiled program shardings before a

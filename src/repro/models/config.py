@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 VOCAB_PAD_MULTIPLE = 2048  # pad vocab so the vocab axis shards cleanly (16-way TP, 128-lane)
 
@@ -32,7 +32,10 @@ class ModelConfig:
     vocab_size: int
     head_dim: Optional[int] = None          # None -> d_model // n_heads
     # attention details ---------------------------------------------------
-    qk_norm: bool = False                    # qwen3-style per-head RMSNorm on q/k
+    # RMSNorm on q/k before RoPE: False off, True per head (qwen3, gemma3),
+    # "full" over the whole projection width before the split into heads
+    # (OLMoE: one (n_heads*hd,) and one (n_kv_heads*hd,) weight)
+    qk_norm: Union[bool, str] = False
     rope_theta: float = 10_000.0
     rope_theta_local: Optional[float] = None  # gemma3: different theta for local layers
     scale_embeddings: bool = False            # gemma/seamless: embed *= sqrt(d_model)
@@ -43,7 +46,9 @@ class ModelConfig:
     # mixture of experts --------------------------------------------------
     n_experts: int = 0
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25             # training dispatch only;
+                                             # serving is drop-free
+    norm_topk_prob: bool = True              # renormalise the top-k gates
     router_aux_coef: float = 0.01
     # state-space (mamba2 / SSD) -----------------------------------------
     ssm_state: int = 0

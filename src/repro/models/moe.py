@@ -3,11 +3,22 @@
 Design (see DESIGN.md §5): activations entering the MoE block are replicated
 over the ``model`` axis (standard Megatron TP layout), expert weights are
 sharded expert-major over ``model``.  Inside a ``shard_map`` each model shard
-routes the *full local token set* to its own E/tp experts with a sort-free,
-capacity-bounded scatter (GShard-style drops, token-order priority), runs the
-expert FFNs as dense (E_local, C, d) batched matmuls, scatters partial outputs
-back and ``psum``s over ``model``.  No all-to-all is required in this layout —
-the only collective is the same psum any TP FFN pays.
+routes the *full local token set* over all E experts and computes the part of
+the output its own E/tp experts give, then ``psum``s over ``model``.  No
+all-to-all is required in this layout — the only collective is the same psum
+any TP FFN pays.
+
+Routing: the router's logits, softmax and top-k are float32 (from the
+served activations); the top-k gates are renormalised to sum to one only
+where ``cfg.norm_topk_prob`` (Qwen3-MoE), and are the plain softmax
+probabilities otherwise (OLMoE).  Two dispatches:
+
+* training: a sort-free, capacity-bounded scatter (GShard-style drops,
+  token-order priority) into dense (E_local, C, d) buffers;
+* serving (prefill and decode): drop-free — every held expert runs over
+  every local token, weighted by its gate, which is zero where the token
+  did not pick it.  Every token gets all of its k experts, so a served
+  token never depends on which requests share its batch.
 
 This is also the arch-level realization of the paper's *dynamic calls* (C4):
 an expert is a "function resident in global memory" that is paged into the
@@ -48,18 +59,49 @@ def _capacity(cfg, tokens_local: int) -> int:
     return max(4, c)
 
 
+def _route(cfg, xf, router):
+    """Router probabilities (T, E) and each token's top-k gates and experts
+    (T, k), all float32."""
+    logits = jnp.einsum("td,de->te", xf, router,
+                        preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return probs, top_p, top_i
+
+
+def _moe_dropfree(cfg, x, router, w_gate, w_up, w_down, *, e_local0,
+                  n_local, model_axis=None):
+    """Per-shard serving body: each held expert over every local token,
+    weighted by its gate (zero where not picked). x: (B_l, S, d)."""
+    b, s, d = x.shape
+    xf = x.reshape(b * s, d)
+    with jax.named_scope("moe/route"):
+        _, top_p, top_i = _route(cfg, xf, router)
+        held = e_local0 + jnp.arange(n_local)
+        gates = jnp.sum(jnp.where(top_i[:, :, None] == held, top_p[:, :, None],
+                                  0.0), axis=1)               # (T, E_l)
+    with jax.named_scope("moe/experts"):
+        h = jax.nn.silu(jnp.einsum("td,edf->etf", xf, w_gate)) * jnp.einsum(
+            "td,edf->etf", xf, w_up)
+        y = jnp.einsum("etf,efd->etd", h, w_down)             # (E_l, T, d)
+    with jax.named_scope("moe/combine"):
+        out = jnp.einsum("etd,te->td", y.astype(jnp.float32), gates)
+        out = out.astype(x.dtype)
+        if model_axis:
+            out = jax.lax.psum(out, model_axis)
+    return out.reshape(b, s, d)
+
+
 def _moe_local(cfg, x, router, w_gate, w_up, w_down, *, e_local0, n_local,
                capacity, model_axis=None, dp_axes=None):
-    """Per-shard MoE body. x: (B_l, S, d); weights: local expert slices."""
+    """Per-shard training body. x: (B_l, S, d); weights: local expert
+    slices."""
     b, s, d = x.shape
     t = b * s
-    k = cfg.experts_per_token
     xf = x.reshape(t, d)
-
-    logits = (xf @ router).astype(jnp.float32)                # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)                    # (T, k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    probs, top_p, top_i = _route(cfg, xf, router)             # (T, E), (T, k)
 
     # load-balance aux loss (Switch): E * sum_e f_e * P_e
     me = jnp.mean(probs, axis=0)                              # (E,)
@@ -104,13 +146,21 @@ def _moe_local(cfg, x, router, w_gate, w_up, w_down, *, e_local0, n_local,
     return out.reshape(b, s, d), aux
 
 
-def apply_moe(cfg, p: Dict[str, Any], x: jax.Array,
-              rules) -> Tuple[jax.Array, jax.Array]:
+def apply_moe(cfg, p: Dict[str, Any], x: jax.Array, rules,
+              mode: str = "train") -> Tuple[jax.Array, jax.Array]:
     """Returns (output, aux_loss). Dispatches to shard_map when a mesh with a
-    ``model`` axis is ambient; otherwise runs the single-shard body."""
+    ``model`` axis is ambient; otherwise runs the single-shard body.  Any
+    ``mode`` but ``"train"`` (prefill, decode) takes the drop-free body, whose
+    aux loss is zero."""
     mesh = get_abstract_mesh_or_none()
     mapped = mesh is not None and not mesh.empty and "model" in mesh.axis_names
+    serving = mode != "train"
+    no_aux = jnp.zeros((), jnp.float32)
     if not mapped:
+        if serving:
+            return _moe_dropfree(cfg, x, p["router"], p["w_gate"], p["w_up"],
+                                 p["w_down"], e_local0=0,
+                                 n_local=cfg.n_experts), no_aux
         cap = _capacity(cfg, x.shape[0] * x.shape[1])
         return _moe_local(cfg, x, p["router"], p["w_gate"], p["w_up"],
                           p["w_down"], e_local0=0, n_local=cfg.n_experts,
@@ -127,6 +177,10 @@ def apply_moe(cfg, p: Dict[str, Any], x: jax.Array,
 
     def body(x_l, router, wg, wu, wd):
         mi = jax.lax.axis_index("model")
+        if serving:
+            return _moe_dropfree(cfg, x_l, router, wg, wu, wd,
+                                 e_local0=mi * n_local, n_local=n_local,
+                                 model_axis="model"), no_aux
         return _moe_local(cfg, x_l, router, wg, wu, wd,
                           e_local0=mi * n_local, n_local=n_local,
                           capacity=cap, model_axis="model", dp_axes=dp_axes)

@@ -173,6 +173,10 @@ def param_counts(cfg: ModelConfig) -> Dict[str, float]:
     for kind in pattern:
         if kind in ("G", "L"):
             n = d * hd * (cfg.n_heads * 2 + cfg.n_kv_heads * 2)
+            if cfg.qk_norm == "full":       # one weight over each projection
+                n += (cfg.n_heads + cfg.n_kv_heads) * hd
+            elif cfg.qk_norm:
+                n += 2 * hd
             total += n
             active += n
         elif kind == "M":

@@ -14,11 +14,13 @@ Modes: "train" (no cache), "prefill" (writes cache), "decode" (one token).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
 from repro.kernels import paged_attention as paged_attn
@@ -75,7 +77,11 @@ def _attn_abstract(cfg) -> Params:
                            ("embed_fsdp", "kv_heads_w")),
         "wo": LogicalArray((cfg.n_heads * hd, d), dt, ("heads", "embed_fsdp")),
     }
-    if cfg.qk_norm:
+    if cfg.qk_norm == "full":
+        p["q_norm"] = LogicalArray((cfg.n_heads * hd,), dt, ("heads",))
+        p["k_norm"] = LogicalArray((cfg.n_kv_heads * hd,), dt,
+                                   ("kv_heads_w",))
+    elif cfg.qk_norm:
         p["q_norm"] = rmsnorm_abstract(hd, dt)
         p["k_norm"] = rmsnorm_abstract(hd, dt)
     return p
@@ -114,22 +120,55 @@ def _decode_kv_spec(cfg):
     return ("batch", None, None, None)
 
 
-def _paged_attention_impl(rules, window: int) -> str:
+def _kv_head_shards(rules) -> Tuple[Tuple[str, ...], int]:
+    """The mesh axes the ambient mesh splits KV heads over (the axes
+    ``rules`` maps them to), and the number of shards; ((), 1) without a
+    mesh."""
+    mesh = get_abstract_mesh_or_none()
+    axes = rules.get("kv_heads")
+    if mesh is None or axes is None:
+        return (), 1
+    axes = axes if isinstance(axes, (tuple, list)) else (axes,)
+    axes = tuple(a for a in axes if a in mesh.axis_names)
+    return axes, math.prod(mesh.shape[a] for a in axes)
+
+
+def _paged_attention_impl(cfg, rules, window: int) -> str:
     """The paged decode read a layer takes: the Pallas kernel over live
     blocks where the backend runs one (``ops.default_impl()``), the layer
     attends its whole context (``window == 0``) and its KV heads are
-    unsharded (no mesh, or one device on the axis ``rules`` maps them to);
-    else the XLA gather of every slot's whole block table."""
+    unsharded or split evenly, query heads alike, over the axes ``rules``
+    maps them to (the kernel then runs per shard); else the XLA gather of
+    every slot's whole block table (the head_dim layout, where the heads
+    do not divide)."""
     impl = ops.default_impl()
     if impl == "xla" or window:
         return "xla"
-    mesh = get_abstract_mesh_or_none()
-    axes = rules.get("kv_heads")
-    if mesh is not None and axes is not None:
-        axes = axes if isinstance(axes, (tuple, list)) else (axes,)
-        if math.prod(mesh.shape.get(a, 1) for a in axes) > 1:
-            return "xla"
+    _, n = _kv_head_shards(rules)
+    if n > 1 and (_cache_heads(cfg) % n or cfg.n_heads % n
+                  or rules.get("heads") != rules.get("kv_heads")):
+        return "xla"
     return impl
+
+
+def _paged_decode_kernel(rules, q, k_arena, v_arena, block_table, lengths,
+                         impl: str):
+    """The kernel over the whole arena, or, where a mesh splits the KV
+    heads, once per shard under ``shard_map``: queries, arenas and output
+    split on heads, block table and lengths replicated, no collective (a
+    shard's query heads read only its own KV heads)."""
+    call = functools.partial(ops.paged_decode_attention, impl=impl)
+    axes, n = _kv_head_shards(rules)
+    if n == 1:
+        return call(q, k_arena, v_arena, block_table, lengths)
+    heads = P(None, axes, None)
+    arena = P(None, None, axes, None)
+    # check_vma off: the kernel's output shape carries no varying axes
+    return jax.shard_map(
+        call, mesh=get_abstract_mesh_or_none(),
+        in_specs=(heads, arena, arena, P(), P()), out_specs=heads,
+        check_vma=False,
+    )(q, k_arena, v_arena, block_table, lengths)
 
 
 def paged_attention_context() -> str:
@@ -195,8 +234,20 @@ def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
                         ).reshape(d, ch * hd)
         wv = jnp.repeat(wv.reshape(d, cfg.n_kv_heads, hd), rep, axis=1
                         ).reshape(d, ch * hd)
-    q = jnp.einsum("bsd,dh->bsh", xn, p["wq"]).reshape(b, s, cfg.n_heads, hd)
-    k = jnp.einsum("bsd,dh->bsh", xn, wk).reshape(b, s, ch, hd)
+    q = jnp.einsum("bsd,dh->bsh", xn, p["wq"])
+    k = jnp.einsum("bsd,dh->bsh", xn, wk)
+    if cfg.qk_norm == "full":
+        # one norm over the whole projection width, before the split into
+        # heads: under a mesh that splits the heads, its mean of squares is
+        # a reduction across devices
+        k_norm = p["k_norm"]
+        if ch != cfg.n_kv_heads:
+            k_norm = jnp.repeat(k_norm.reshape(cfg.n_kv_heads, hd),
+                                ch // cfg.n_kv_heads, axis=0).reshape(-1)
+        q = apply_rmsnorm(p["q_norm"], q, cfg.norm_eps)
+        k = apply_rmsnorm(k_norm, k, cfg.norm_eps)
+    q = q.reshape(b, s, cfg.n_heads, hd)
+    k = k.reshape(b, s, ch, hd)
     v = jnp.einsum("bsd,dh->bsh", xn, wv).reshape(b, s, ch, hd)
     q = constrain(q, ("batch", "seq_attn", "heads", None), rules)
     if ch != cfg.n_kv_heads:
@@ -207,7 +258,7 @@ def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
         # so the cache write can't back-propagate a conflicting sharding
         k = constrain(k, ("batch", "seq_attn", None, None), rules)
         v = constrain(v, ("batch", "seq_attn", None, None), rules)
-    if cfg.qk_norm:
+    if cfg.qk_norm and cfg.qk_norm != "full":
         q = apply_rmsnorm(p["q_norm"], q, cfg.norm_eps)
         k = apply_rmsnorm(p["k_norm"], k, cfg.norm_eps)
 
@@ -240,7 +291,7 @@ def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
                                                   pos_b, k[:, 0], live=live)
                 v_arena = attn_mod.write_paged_kv(cache["v"], block_table,
                                                   pos_b, v[:, 0], live=live)
-            impl = _paged_attention_impl(rules, window)
+            impl = _paged_attention_impl(cfg, rules, window)
             if impl == "xla":
                 with jax.named_scope("paged_kv/gather"):
                     k_log = attn_mod.gather_paged_kv(k_arena, block_table)
@@ -250,9 +301,9 @@ def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
                         q, k_log, v_log, pos_b + 1, window=window, ring=False)
             else:
                 with jax.named_scope("paged_kv/attend"):
-                    out = ops.paged_decode_attention(
-                        q[:, 0], k_arena, v_arena, block_table, pos_b + 1,
-                        impl=impl)[:, None]
+                    out = _paged_decode_kernel(
+                        rules, q[:, 0], k_arena, v_arena, block_table,
+                        pos_b + 1, impl)[:, None]
             out = constrain(out, out_spec, rules)
             out = jnp.einsum("bsh,hd->bsd",
                              out.reshape(b, s, cfg.n_heads * hd), p["wo"])
@@ -387,10 +438,11 @@ def apply_layer(cfg, kind: str, p: Params, x, *, rules, mode, cache, pos,
     if cfg.d_ff > 0:
         residual = x
         xn = apply_rmsnorm(p["ffn_ln"], x, cfg.norm_eps)
-        if cfg.family == "moe":
-            out, aux = moe_mod.apply_moe(cfg, p["moe"], xn, rules)
-        else:
-            with jax.named_scope("mlp"):
+        with jax.named_scope("mlp"):
+            if cfg.family == "moe":
+                out, aux = moe_mod.apply_moe(cfg, p["moe"], xn, rules,
+                                             mode=mode)
+            else:
                 out = apply_mlp(p["mlp"], xn, rules)
         x = residual + out
     return x, new_cache, aux

@@ -11,14 +11,15 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro import steps
-from repro.engine_config import EngineConfig, PagingConfig
+from repro.engine_config import EngineConfig, HorizonConfig, PagingConfig
 from repro.kernels import ops
 from repro.models import registry
-from repro.sharding import make_rules, tree_structs
+from repro.sharding import make_rules, tree_shardings, tree_structs
 
 HBM_BYTES = 16e9                # one TPU v5e chip
 ARCH = "qwen3-0.6b"             # at its published widths
@@ -26,10 +27,10 @@ SERVE = EngineConfig(reduced=False, batch=8, max_len=2048)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described ``v5e:2x2``, with the persistent compilation
-    cache off: an entry compiled for a described chip cannot be read back
-    without one."""
+def topo():
+    """A described ``v5e:2x2``, with the persistent compilation cache off:
+    an entry compiled for a described chip cannot be read back without
+    one."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     mp = pytest.MonkeyPatch()
@@ -44,10 +45,21 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
         mp.undo()
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache_was_on)
     compilation_cache.reset_cache()
     mp.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The serving mesh over all four chips of the described host."""
+    return Mesh(np.array(topo.devices).reshape(4), ("model",))
 
 
 @pytest.fixture(scope="module")
@@ -110,4 +122,34 @@ def test_paged_decode_with_kernel_fits_one_v5e_chip(one_chip, monkeypatch):
         *args).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES, mem
+    assert "paged_decode_attention" in compiled.as_text()
+
+
+def test_olmoe_decode_horizon_fits_four_v5e_chips(four_chips, monkeypatch):
+    """OLMoE-1B-7B whole, at its published widths, over the four chips of a
+    described v5e host as the chip benchmark serves it (64 slots, 2,048
+    positions, a 5,120-block arena of 16-token blocks, horizons of 8):
+    ``decode_horizon`` compiles with the paged-attention kernel run per KV
+    head shard, and each chip's arguments (3.46 GB of weights, 2.68 GB of
+    arena) and temporaries fit its HBM."""
+    monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
+    cfg = registry.get_config("olmoe-1b-7b", reduced=False)
+    config = EngineConfig(reduced=False, batch=64, max_len=2048,
+                          prefill_len=1024, horizon=HorizonConfig(length=8),
+                          paging=PagingConfig(kv_block=16, arena_blocks=5120))
+    rules = make_rules()
+    spec = steps.serve_program_specs(cfg, rules, config)["decode_horizon"]
+    shardings = tree_shardings(spec.abstract_args, rules, four_chips)
+    args = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        tree_structs(spec.abstract_args), shardings)
+    with jax.set_mesh(four_chips):
+        compiled = jax.jit(
+            spec.fn, in_shardings=shardings,
+            out_shardings=tree_shardings(spec.out_logical, rules, four_chips),
+            donate_argnums=spec.donate_argnums).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert 6.0e9 < mem.argument_size_in_bytes < 6.3e9, mem
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES, mem
+    assert mem.alias_size_in_bytes >= 2.68e9, mem
     assert "paged_decode_attention" in compiled.as_text()

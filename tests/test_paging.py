@@ -16,6 +16,12 @@ Three layers of coverage:
 """
 import contextlib
 import functools
+import json
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -29,6 +35,22 @@ from repro.launch.serve import (METRIC_ARENA_OCCUPANCY, METRIC_PAGE_FAULT,
                                 ServingEngine)
 from repro.models import registry, transformer
 from repro.sharding import make_rules
+
+TESTS = Path(__file__).resolve().parent
+
+
+def run_on_devices(n: int, code: str) -> dict:
+    """Run ``code`` in a subprocess that sees ``n`` CPU devices (and this
+    directory on its path); its last line of output is JSON."""
+    env = dict(os.environ,
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={n}",
+               PYTHONPATH=os.pathsep.join([str(TESTS.parent / "src"),
+                                           str(TESTS)]))
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -364,24 +386,80 @@ def test_paged_kernel_decode_matches_xla_path(monkeypatch):
     np.testing.assert_array_equal(x_horizon, x_toks)
 
 
-@pytest.mark.parametrize("case", ["kernel", "window", "sharded_kv_heads"])
+@pytest.mark.parametrize("case", ["kernel", "window", "sharded_kv_heads",
+                                  "head_dim_layout"])
 def test_paged_kernel_selection(monkeypatch, case):
-    """The paged decode program holds the kernel only where the layer
-    attends its whole context and its KV heads are unsharded: a
-    local-window layer and KV heads split over a mesh keep the XLA
-    gather."""
+    """The paged decode program holds the kernel where the layer attends
+    its whole context and its KV heads are unsharded, or split evenly over
+    a mesh (then once per shard, under ``shard_map``); a local-window layer
+    and KV heads that do not divide the mesh (the head_dim layout) keep
+    the XLA gather.  Without a mesh no ``shard_map`` is traced."""
     monkeypatch.setattr(ops, "default_impl", lambda: "interpret")
-    cfg = registry.get_config("qwen3-0.6b", reduced=True)
+    cfg = registry.get_config("qwen3-0.6b", reduced=True)   # 2 KV heads
     mesh = contextlib.nullcontext()
     if case == "window":
         cfg = cfg.replace(layer_pattern=("L",), local_window=8)
-    elif case == "sharded_kv_heads":
+    elif case in ("sharded_kv_heads", "head_dim_layout"):
+        n = 2 if case == "sharded_kv_heads" else 4
         mesh = jax.sharding.use_abstract_mesh(
-            jax.sharding.AbstractMesh((2,), ("model",)))
+            jax.sharding.AbstractMesh((n,), ("model",)))
     params = jax.eval_shape(
         lambda: transformer.init_params(cfg, jax.random.PRNGKey(0)))
     caches, tok = _paged_decode_setup(cfg)
     step = functools.partial(transformer.decode_step, cfg, rules=make_rules())
     with mesh:
         text = str(jax.make_jaxpr(step)(params, caches, tok))
-    assert ("paged_decode_attention" in text) == (case == "kernel")
+    assert ("paged_decode_attention" in text) == (case in ("kernel",
+                                                           "sharded_kv_heads"))
+    assert ("shard_map" in text) == (case == "sharded_kv_heads")
+    if case == "kernel":
+        # the mesh-less read: the kernel, called once in the layer scan
+        assert text.count("pallas_call") == 1
+
+
+def test_paged_kernel_per_kv_head_shard_matches_xla_path():
+    """On a 4-device CPU mesh that splits 4 KV heads (8 query heads) one a
+    device, the paged ``decode_step`` with the kernel run per shard
+    (interpreted) gives the XLA gather path's greedy tokens and logits
+    over 8 steps, with a shuffled table, read-only shared blocks and an
+    unmapped row (``_paged_decode_setup``).  The tolerance is float32
+    reduction order: the kernel's online softmax against one softmax."""
+    res = run_on_devices(4, """
+        import functools, json
+        import jax, numpy as np
+        from repro.kernels import ops
+        from repro.launch.mesh import serving_mesh
+        from repro.models import registry, transformer
+        from repro.sharding import make_rules, tree_shardings
+        from test_paging import _paged_decode_setup
+
+        cfg = registry.get_config("qwen3-0.6b", reduced=True).replace(
+            n_heads=8, n_kv_heads=4, d_model=128)
+        rules, mesh = make_rules(), serving_mesh(4)
+        params = jax.device_put(
+            transformer.init_params(cfg, jax.random.PRNGKey(0)),
+            tree_shardings(transformer.abstract_params(cfg), rules, mesh))
+        out = {}
+        for impl in ("interpret", "xla"):
+            ops.default_impl = lambda: impl
+            caches, tok = _paged_decode_setup(cfg)
+            step = jax.jit(functools.partial(transformer.decode_step, cfg,
+                                             rules=rules))
+            toks, logits = [], []
+            with jax.set_mesh(mesh):
+                text = str(step.trace(params, caches, tok).jaxpr)
+                for _ in range(8):
+                    o, caches = step(params, caches, tok)
+                    tok = transformer.greedy_token(cfg, o)
+                    toks.append(np.asarray(tok[:2, 0]).tolist())
+                    logits.append(np.asarray(o[:2, 0]))
+            out[impl] = {"kernel": "paged_decode_attention" in text,
+                         "shard_map": "shard_map" in text, "toks": toks,
+                         "logits": np.stack(logits).tolist()}
+        print(json.dumps(out))
+    """)
+    k, x = res["interpret"], res["xla"]
+    assert k["kernel"] and k["shard_map"]
+    assert not x["kernel"]
+    assert k["toks"] == x["toks"]
+    np.testing.assert_allclose(k["logits"], x["logits"], rtol=1e-4, atol=1e-4)

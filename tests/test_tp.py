@@ -156,3 +156,45 @@ def test_tp_mesh_goes_through_serving_mesh():
                           "axis_names": list(mesh.axis_names)}))
     """)
     assert res["same"] and res["axis_names"] == ["model"]
+
+
+def test_tp_engine_makes_its_cache_on_the_mesh():
+    """A sharded engine makes its cache tree inside a program whose outputs
+    are the tree's shardings, so no device ever holds a whole leaf (a
+    model's arena spread over several chips need not fit one); the
+    mesh-less engine makes it as plain arrays."""
+    res = _run("""
+        import json
+        import jax
+        from repro.launch.serve import (ServingEngine, EngineConfig,
+                                        PagingConfig, ShardConfig)
+        from repro.models import transformer
+
+        traced = []
+        for name in ("init_paged_cache", "init_cache"):
+            make = getattr(transformer, name)
+            def spy(*a, make=make, **k):
+                tree = make(*a, **k)
+                traced.append(all(isinstance(x, jax.core.Tracer)
+                                  for x in jax.tree.leaves(tree)))
+                # the prefill programs make a fresh cache too, while they
+                # compile: the engine's own tree is the last one made
+                return tree
+            setattr(transformer, name, spy)
+        base = EngineConfig(batch=2, max_len=32, prefill_len=8, clock="step")
+        out = {}
+        for name, cfg in {"paged": base.replace(
+                              paging=PagingConfig(kv_block=8)),
+                          "dense": base}.items():
+            for n in (1, 8):
+                traced.clear()
+                eng = ServingEngine("qwen3-0.6b", cfg.replace(
+                    shard=ShardConfig(n_devices=n)))
+                arena = jax.tree.leaves(eng.caches["groups"])[0]
+                out[f"{name}{n}"] = {"traced": traced[-1],
+                                     "devices": len(arena.sharding.device_set)}
+        print(json.dumps(out))
+    """)
+    for name in ("paged", "dense"):
+        assert res[f"{name}1"] == {"traced": False, "devices": 1}, res
+        assert res[f"{name}8"] == {"traced": True, "devices": 8}, res
