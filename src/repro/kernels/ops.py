@@ -87,16 +87,17 @@ def moe_ffn(buf, w1, w3, w2, *, impl: Optional[str] = None,
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "blocks_per_wave"))
-def paged_decode_attention(q, k_arena, v_arena, block_table, lengths, *,
-                           impl: Optional[str] = None,
+def paged_decode_attention(q, k_arena, v_arena, block_table, lengths, layer,
+                           *, impl: Optional[str] = None,
                            blocks_per_wave: int = 8):
-    """q (B, H, D) against each row's live blocks of (P, bs, Hkv, D)
-    arenas through ``block_table`` (B, M); ``lengths`` (B,)."""
+    """q (B, H, D) against each row's live blocks of layer ``layer`` of
+    (L, P, bs, Hkv, D) arenas through ``block_table`` (B, M); ``lengths``
+    (B,)."""
     impl = _resolve(impl)
     if impl == "xla":
         return ref.paged_decode_attention(q, k_arena, v_arena, block_table,
-                                          lengths)
+                                          lengths, layer)
     return _pa.paged_decode_attention(q, k_arena, v_arena, block_table,
-                                      lengths,
+                                      lengths, layer,
                                       blocks_per_wave=blocks_per_wave,
                                       interpret=(impl == "interpret"))

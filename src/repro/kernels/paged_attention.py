@@ -1,8 +1,10 @@
 """Paged decode attention: one query token per slot against its live blocks.
 
-The KV arena keeps its serving layout ``(P, bs, Hkv, D)`` (physical block,
-offset, kv head, head dim) and stays in HBM; the kernel DMAs only each
-slot's live blocks, found through the block table, into VMEM.  Nothing is
+The KV arenas keep their serving layout ``(L, P, bs, Hkv, D)`` (layer,
+physical block, offset, kv head, head dim) and stay in HBM; the kernel
+reads one layer's arena by its index, so the stack a layer scan carries is
+handed over whole, never sliced, and DMAs only each slot's live blocks,
+found through the block table, into VMEM.  Nothing is
 gathered into a per-slot logical cache and nothing is repeated to the query
 heads: the XLA path copies every slot's whole table out of the arena and
 repeats it ``G`` times, so its cost follows ``slots x max_len``; this one
@@ -42,8 +44,9 @@ NEG_INF = -1e30
 
 # Folded into the serving programs' fingerprint context: bump it with any
 # change to what the kernel computes or how it is scheduled (2: it also
-# runs once per shard of the KV heads where a mesh splits them).
-VERSION = "paged_decode_attention/2"
+# runs once per shard of the KV heads where a mesh splits them; 3: it
+# reads a stack of layer arenas by layer index).
+VERSION = "paged_decode_attention/3"
 
 
 def _live_lengths(block_table: jax.Array, lengths: jax.Array,
@@ -57,13 +60,14 @@ def _live_lengths(block_table: jax.Array, lengths: jax.Array,
     return jnp.minimum(lengths.astype(jnp.int32), first * block_size)
 
 
-def _kernel(lens_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
+def _kernel(lens_ref, table_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
             k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref, *,
             n_rows: int, table_w: int, block_size: int, nb: int,
             group: int, scale: float):
     b = pl.program_id(0)
     hkv = k_buf.shape[3]
     wave = nb * block_size
+    layer = layer_ref[0]
 
     def live_blocks(row):
         return (lens_ref[row] + block_size - 1) // block_size
@@ -74,10 +78,10 @@ def _kernel(lens_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
     def copies(row, w, slot, j):
         e = table_ref[row * table_w + w * nb + j]
         phys = jnp.where(e >= 0, e, -e - 2)
-        return (pltpu.make_async_copy(k_hbm.at[phys], k_buf.at[slot, j],
-                                      sems.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[phys], v_buf.at[slot, j],
-                                      sems.at[1, slot]))
+        return (pltpu.make_async_copy(k_hbm.at[layer, phys],
+                                      k_buf.at[slot, j], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, phys],
+                                      v_buf.at[slot, j], sems.at[1, slot]))
 
     def each_block(row, w, slot, op):
         n = n_blocks(row, w)
@@ -154,13 +158,14 @@ def _kernel(lens_ref, table_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("blocks_per_wave", "interpret"))
-def paged_decode_attention(q, k_arena, v_arena, block_table, lengths, *,
-                           blocks_per_wave: int = 8, interpret=False):
-    """q: (B, H, D); k/v_arena: (P, bs, Hkv, D) with H % Hkv == 0;
-    block_table: (B, M) int32; lengths: (B,) positions to attend (``pos + 1``
-    in decode).  Returns (B, H, D) in q's dtype."""
+def paged_decode_attention(q, k_arena, v_arena, block_table, lengths, layer,
+                           *, blocks_per_wave: int = 8, interpret=False):
+    """q: (B, H, D); k/v_arena: (L, P, bs, Hkv, D) with H % Hkv == 0, read
+    at ``layer`` (an int32 scalar); block_table: (B, M) int32; lengths: (B,)
+    positions to attend (``pos + 1`` in decode).  Returns (B, H, D) in q's
+    dtype."""
     bsz, h, d = q.shape
-    _, bs, hkv, _ = k_arena.shape
+    _, _, bs, hkv, _ = k_arena.shape
     m = block_table.shape[1]
     assert h % hkv == 0, (h, hkv)
     nb = min(blocks_per_wave, m)
@@ -172,7 +177,7 @@ def paged_decode_attention(q, k_arena, v_arena, block_table, lengths, *,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(bsz,),
             in_specs=[row_block,
                       pl.BlockSpec(memory_space=pl.ANY),
@@ -192,4 +197,5 @@ def paged_decode_attention(q, k_arena, v_arena, block_table, lengths, *,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(lens, block_table.reshape(-1).astype(jnp.int32), q, k_arena, v_arena)
+    )(lens, block_table.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, k_arena, v_arena)

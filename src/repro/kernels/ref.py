@@ -87,10 +87,10 @@ def moe_ffn(buf, w1, w3, w2):
     return jnp.einsum("ecf,efd->ecd", h, w2)
 
 
-def paged_decode_attention(q, k_arena, v_arena, block_table, lengths):
+def paged_decode_attention(q, k_arena, v_arena, block_table, lengths, layer):
     """The XLA paged decode read: gather every row's whole block table out
-    of the arena, repeat it to the query heads, attend the first
-    ``lengths`` positions.  q: (B, H, D); arenas: (P, bs, Hkv, D)."""
-    k = attn.gather_paged_kv(k_arena, block_table)
-    v = attn.gather_paged_kv(v_arena, block_table)
+    of the arena ``layer``, repeat it to the query heads, attend the first
+    ``lengths`` positions.  q: (B, H, D); arenas: (L, P, bs, Hkv, D)."""
+    k = attn.gather_paged_kv(k_arena, layer, block_table)
+    v = attn.gather_paged_kv(v_arena, layer, block_table)
     return attn.decode_attention(q[:, None], k, v, lengths)[:, 0]

@@ -178,32 +178,36 @@ def _valid_cache_slots(cache_len: jax.Array, b: int, c: int, *, window: int,
     return valid
 
 
-def gather_paged_kv(arena: jax.Array, block_table: jax.Array) -> jax.Array:
+def gather_paged_kv(arena: jax.Array, layer,
+                    block_table: jax.Array) -> jax.Array:
     """Block-table-indexed cache read (the paged-KV jump-table dereference).
 
-    arena: (P, bs, H, D) physical blocks; block_table: (B, M) physical block
-    id per logical block, -1 = unmapped, ``-(p + 2)`` = physical block p
-    mapped READ-ONLY (a cross-request shared prefix block — the write path
-    keys its guard on ``phys >= 0``, so the encoding makes shared blocks
-    unwritable for free while this gather decodes them back).  Returns the
-    logical per-row cache (B, M*bs, H, D): logical block j of row b is
-    arena[decode(block_table[b, j])].  Unmapped entries clamp to block 0
-    and read garbage — callers mask them through the valid-length check of
-    ``decode_attention``.
+    arena: a stack of layer arenas (L, P, bs, H, D) of physical blocks,
+    read at ``layer``; block_table: (B, M) physical block id per logical
+    block, -1 = unmapped, ``-(p + 2)`` = physical block p mapped READ-ONLY
+    (a cross-request shared prefix block — the write path keys its guard
+    on ``phys >= 0``, so the encoding makes shared blocks unwritable for
+    free while this gather decodes them back).  Returns the logical
+    per-row cache (B, M*bs, H, D): logical block j of row b is
+    arena[layer, decode(block_table[b, j])].  Unmapped entries clamp to
+    block 0 and read garbage — callers mask them through the valid-length
+    check of ``decode_attention``.
     """
     b, m = block_table.shape
-    bs = arena.shape[1]
+    bs = arena.shape[2]
     phys = jnp.where(block_table >= 0, block_table, -block_table - 2)
-    gathered = arena[jnp.clip(phys, 0)]
-    return gathered.reshape(b, m * bs, *arena.shape[2:])
+    gathered = arena[layer, jnp.clip(phys, 0)]
+    return gathered.reshape(b, m * bs, *arena.shape[3:])
 
 
-def write_paged_kv(arena: jax.Array, block_table: jax.Array, pos: jax.Array,
-                   val: jax.Array, live=None) -> jax.Array:
+def write_paged_kv(arena: jax.Array, layer, block_table: jax.Array,
+                   pos: jax.Array, val: jax.Array, live=None) -> jax.Array:
     """Block-table-indexed cache write of one token per row.
 
-    Row b's value (B, H, D) lands in physical block
-    ``block_table[b, pos[b] // bs]`` at offset ``pos[b] % bs``.  Rows whose
+    arena: a stack of layer arenas (L, P, bs, H, D); row b's value (B, H,
+    D) lands in layer ``layer``'s physical block
+    ``block_table[b, pos[b] // bs]`` at offset ``pos[b] % bs``: one scatter
+    into the stack, which a loop carrying it updates in place.  Rows whose
     block is unmapped (released slots, table entry -1) are dropped — and so
     is any write aimed at a READ-ONLY shared-prefix mapping (encoded
     ``-(p + 2)``, see :func:`gather_paged_kv`): the ``phys >= 0`` guard is
@@ -217,7 +221,7 @@ def write_paged_kv(arena: jax.Array, block_table: jax.Array, pos: jax.Array,
     decode horizon holds a finished row's state still while the other rows
     keep stepping); ``None`` = all rows write.
     """
-    p, bs = arena.shape[0], arena.shape[1]
+    p, bs = arena.shape[1], arena.shape[2]
     m = block_table.shape[1]
     blk = pos // bs
     phys = jnp.take_along_axis(block_table, jnp.clip(blk, 0, m - 1)[:, None],
@@ -226,7 +230,8 @@ def write_paged_kv(arena: jax.Array, block_table: jax.Array, pos: jax.Array,
     if live is not None:
         writable &= live
     dest = jnp.where(writable, phys, p)
-    return arena.at[dest, pos % bs].set(val.astype(arena.dtype), mode="drop")
+    return arena.at[layer, dest, pos % bs].set(val.astype(arena.dtype),
+                                               mode="drop")
 
 
 def rollback_paged_kv(arena: jax.Array, orig: jax.Array,

@@ -152,23 +152,24 @@ def _paged_attention_impl(cfg, rules, window: int) -> str:
 
 
 def _paged_decode_kernel(rules, q, k_arena, v_arena, block_table, lengths,
-                         impl: str):
-    """The kernel over the whole arena, or, where a mesh splits the KV
-    heads, once per shard under ``shard_map``: queries, arenas and output
-    split on heads, block table and lengths replicated, no collective (a
-    shard's query heads read only its own KV heads)."""
+                         layer, impl: str):
+    """The kernel over the whole stack of arenas at ``layer``, or, where a
+    mesh splits the KV heads, once per shard under ``shard_map``: queries,
+    arenas and output split on heads, block table, lengths and layer
+    replicated, no collective (a shard's query heads read only its own KV
+    heads)."""
     call = functools.partial(ops.paged_decode_attention, impl=impl)
     axes, n = _kv_head_shards(rules)
     if n == 1:
-        return call(q, k_arena, v_arena, block_table, lengths)
+        return call(q, k_arena, v_arena, block_table, lengths, layer)
     heads = P(None, axes, None)
-    arena = P(None, None, axes, None)
+    arena = P(None, None, None, axes, None)
     # check_vma off: the kernel's output shape carries no varying axes
     return jax.shard_map(
         call, mesh=get_abstract_mesh_or_none(),
-        in_specs=(heads, arena, arena, P(), P()), out_specs=heads,
+        in_specs=(heads, arena, arena, P(), P(), P()), out_specs=heads,
         check_vma=False,
-    )(q, k_arena, v_arena, block_table, lengths)
+    )(q, k_arena, v_arena, block_table, lengths, layer)
 
 
 def paged_attention_context() -> str:
@@ -212,7 +213,7 @@ def _write_prefill_cache(cache_kv, full, window: int, lengths=None):
 
 
 def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
-                block_table=None, live=None):
+                block_table=None, live=None, layer=None):
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
     window = cfg.local_window if kind == "L" else 0
@@ -276,10 +277,14 @@ def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
         k = attn_mod.repeat_kv(k, ch)
         v = attn_mod.repeat_kv(v, ch)
         if block_table is not None:
-            # paged KV: the cache leaf is a (P, bs, ch, hd) physical-block
-            # arena shared by every slot; this row's write destination and
-            # its reads both resolve through the block table (the
-            # data-page jump table of repro.core.paging).  The read is the
+            # paged KV: the cache leaf is a stack of (P, bs, ch, hd)
+            # physical-block arenas shared by every slot, one per layer,
+            # and this layer's is ``layer``: the write scatters into the
+            # stack and the read indexes it, so nothing slices the layer's
+            # arena out (_run_stack carries the stack through its scan).
+            # This row's write destination and its reads both resolve
+            # through the block table (the data-page jump table of
+            # repro.core.paging).  The read is the
             # Pallas kernel over each row's live blocks where
             # _paged_attention_impl allows it, else the XLA gather of the
             # whole table with the simple full-repeat attention (no
@@ -287,15 +292,17 @@ def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
             # work in each operation's metadata, so a device trace can
             # charge it to this layer.
             with jax.named_scope("paged_kv/write"):
-                k_arena = attn_mod.write_paged_kv(cache["k"], block_table,
-                                                  pos_b, k[:, 0], live=live)
-                v_arena = attn_mod.write_paged_kv(cache["v"], block_table,
-                                                  pos_b, v[:, 0], live=live)
+                k_arena = attn_mod.write_paged_kv(
+                    cache["k"], layer, block_table, pos_b, k[:, 0], live=live)
+                v_arena = attn_mod.write_paged_kv(
+                    cache["v"], layer, block_table, pos_b, v[:, 0], live=live)
             impl = _paged_attention_impl(cfg, rules, window)
             if impl == "xla":
                 with jax.named_scope("paged_kv/gather"):
-                    k_log = attn_mod.gather_paged_kv(k_arena, block_table)
-                    v_log = attn_mod.gather_paged_kv(v_arena, block_table)
+                    k_log = attn_mod.gather_paged_kv(k_arena, layer,
+                                                     block_table)
+                    v_log = attn_mod.gather_paged_kv(v_arena, layer,
+                                                     block_table)
                 with jax.named_scope("paged_kv/attend"):
                     out = attn_mod.decode_attention(
                         q, k_log, v_log, pos_b + 1, window=window, ring=False)
@@ -303,7 +310,7 @@ def _apply_attn(cfg, p: Params, x, *, rules, mode, cache, pos, kind,
                 with jax.named_scope("paged_kv/attend"):
                     out = _paged_decode_kernel(
                         rules, q[:, 0], k_arena, v_arena, block_table,
-                        pos_b + 1, impl)[:, None]
+                        pos_b + 1, layer, impl)[:, None]
             out = constrain(out, out_spec, rules)
             out = jnp.einsum("bsh,hd->bsd",
                              out.reshape(b, s, cfg.n_heads * hd), p["wo"])
@@ -419,12 +426,13 @@ def layer_cache_abstract(cfg, kind: str, batch: int, cache_len: int,
 
 
 def apply_layer(cfg, kind: str, p: Params, x, *, rules, mode, cache, pos,
-                block_table=None, live=None):
+                block_table=None, live=None, layer=None):
     aux = jnp.zeros((), jnp.float32)
     if kind in ATTN_KINDS:
         x, new_cache = _apply_attn(cfg, p["mix"], x, rules=rules, mode=mode,
                                    cache=cache, pos=pos, kind=kind,
-                                   block_table=block_table, live=live)
+                                   block_table=block_table, live=live,
+                                   layer=layer)
     elif kind == "M":
         x, new_cache = ssm_mod.apply_ssm_layer(cfg, p["mix"], x, rules=rules,
                                                mode=mode, cache=cache,
@@ -571,42 +579,64 @@ def _run_stack(cfg, params, x, *, rules, mode, caches, pos, block_table=None,
                live=None):
     unit, n_groups, tail = split_layers(cfg)
     aux0 = jnp.zeros((), jnp.float32)
+    # Paged decode: each attention slot's stacked arenas (n_groups, P, bs,
+    # Hkv, D) ride in the scan's carry and every layer writes and reads them
+    # by its index, so the loop updates them in place.  Scanned as xs/ys
+    # they would be sliced out layer by layer and stacked back into a fresh
+    # buffer, whole-arena copies on every step.  Per-slot recurrent state
+    # stays in xs/ys.
+    paged = block_table is not None
+    carried = [f"slot{i}" for i, k in enumerate(unit)
+               if paged and k in ATTN_KINDS]
 
     def group_body(carry, xs):
-        x, aux = carry
-        if mode == "train":
-            gp, gc = xs, None
-        else:
-            gp, gc = xs
-        new_gc = {}
+        x, aux, arenas = carry
+        gp, gc, layer = xs
+        arenas, new_gc = dict(arenas), {}
         for i, kind in enumerate(unit):
             slot = f"slot{i}"
+            if slot in arenas:
+                cache = arenas[slot]
+            else:
+                cache = None if gc is None else gc[slot]
             x, nc, a = apply_layer(
-                cfg, kind, gp[slot], x, rules=rules, mode=mode,
-                cache=None if gc is None else gc[slot], pos=pos,
-                block_table=block_table, live=live)
-            new_gc[slot] = nc
+                cfg, kind, gp[slot], x, rules=rules, mode=mode, cache=cache,
+                pos=pos, block_table=block_table, live=live, layer=layer)
+            if slot in arenas:
+                arenas[slot] = nc
+            else:
+                new_gc[slot] = nc
             aux = aux + a
         x = constrain(x, ("batch", "seq", "embed"), rules)
-        if mode == "train":
-            return (x, aux), None
-        return (x, aux), new_gc
+        return (x, aux, arenas), (None if mode == "train" else new_gc)
 
     body = _maybe_remat(cfg, group_body, mode)
     if n_groups > 0:
-        xs = params["groups"] if mode == "train" else (params["groups"],
-                                                       caches["groups"])
-        (x, aux), new_group_caches = jax.lax.scan(body, (x, aux0), xs)
+        gc = None if mode == "train" else dict(caches["groups"])
+        arenas = {slot: gc.pop(slot) for slot in carried}
+        layers = jnp.arange(n_groups, dtype=jnp.int32) if carried else None
+        (x, aux, arenas), new_group_caches = jax.lax.scan(
+            body, (x, aux0, arenas), (params["groups"], gc, layers))
+        if carried:
+            new_group_caches = {**new_group_caches, **arenas}
     else:
         new_group_caches, aux = None, aux0
 
     new_tail = {}
     for i, kind in enumerate(tail):
         name = f"tail{i}"
+        cache = None if caches is None else caches["tail"][name]
+        # an unscanned layer's arenas go through the same path as a stack
+        # of one, read and written at layer 0 (the added axis is a bitcast)
+        stacked = paged and kind in ATTN_KINDS
+        if stacked:
+            cache = jax.tree.map(lambda a: a[None], cache)
         x, nc, a = apply_layer(
             cfg, kind, params["tail"][name], x, rules=rules, mode=mode,
-            cache=None if caches is None else caches["tail"][name], pos=pos,
-            block_table=block_table, live=live)
+            cache=cache, pos=pos, block_table=block_table, live=live,
+            layer=jnp.int32(0) if stacked else None)
+        if stacked:
+            nc = jax.tree.map(lambda a: a[0], nc)
         new_tail[name] = nc
         aux = aux + a
 

@@ -8,6 +8,7 @@ fixture, so collecting this file never loads the TPU library; where it
 cannot be described, every test here skips from that fixture.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -106,23 +107,55 @@ def test_flash_attention_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_with_kernel_fits_one_v5e_chip(one_chip, monkeypatch):
-    """The paged ``decode`` program at the chip benchmark's engine size (32
-    slots, 2,048 positions, a 2,560-block arena of 16-token blocks), with
-    the paged read the TPU backend selects, compiles for one chip with the
-    Pallas kernel in it and fits the chip's HBM."""
+def _arena_copies(text, stack, layer):
+    """The instructions of compiled HLO ``text`` that copy, restack or
+    allocate a whole stack of layer arenas (shape ``stack``, e.g.
+    ``bf16[28,2560,16,8,128]``) or slice or copy out one layer's arena
+    (shape ``layer``): what a layer scan that threads the arenas through
+    its xs and ys makes on every step.  In-place updates (the write's
+    scatter) and the kernel's reads are not listed."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\w+\[[\d,]*\])\S* ([\w-]+)\(",
+                     line)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        if shape == stack and (op in ("copy", "dynamic-update-slice")
+                               or 'custom_call_target="AllocateBuffer"'
+                               in line):
+            found.append(name)
+        if shape == layer and op in ("copy-done", "dynamic-slice"):
+            found.append(name)
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "decode_horizon"])
+def test_paged_decode_with_kernel_fits_one_v5e_chip(one_chip, monkeypatch,
+                                                     program):
+    """The paged ``decode`` and ``decode_horizon`` programs at the chip
+    benchmark's engine size (32 slots, 2,048 positions, a 2,560-block arena
+    of 16-token blocks, horizons of 8), with the paged read the TPU backend
+    selects, compile for one chip with the Pallas kernel in them and fit
+    the chip's HBM.  The layer scan updates the stacked arenas in place:
+    no whole-arena copy, restacking or buffer, no per-layer slice or copy
+    of an arena, and under 1 GB of temporaries beside the 4.7 GB arena."""
     monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
     cfg = registry.get_config(ARCH, reduced=False)
     config = EngineConfig(reduced=False, batch=32, max_len=2048,
-                          prefill_len=1024,
+                          prefill_len=1024, horizon=HorizonConfig(length=8),
                           paging=PagingConfig(kv_block=16, arena_blocks=2560))
-    spec = steps.serve_program_specs(cfg, make_rules(), config)["decode"]
+    spec = steps.serve_program_specs(cfg, make_rules(), config)[program]
     args = _on(one_chip, tree_structs(spec.abstract_args))
     compiled = jax.jit(spec.fn, donate_argnums=spec.donate_argnums).lower(
         *args).compile()
     mem = compiled.memory_analysis()
+    text = compiled.as_text()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES, mem
-    assert "paged_decode_attention" in compiled.as_text()
+    assert mem.temp_size_in_bytes < 1e9, mem
+    assert "paged_decode_attention" in text
+    assert _arena_copies(text, "bf16[28,2560,16,8,128]",
+                         "bf16[2560,16,8,128]") == []
 
 
 def test_olmoe_decode_horizon_fits_four_v5e_chips(four_chips, monkeypatch):
@@ -131,7 +164,8 @@ def test_olmoe_decode_horizon_fits_four_v5e_chips(four_chips, monkeypatch):
     positions, a 5,120-block arena of 16-token blocks, horizons of 8):
     ``decode_horizon`` compiles with the paged-attention kernel run per KV
     head shard, and each chip's arguments (3.46 GB of weights, 2.68 GB of
-    arena) and temporaries fit its HBM."""
+    arena) and temporaries fit its HBM, with no copy, restacking or slice
+    of its share of the arena."""
     monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
     cfg = registry.get_config("olmoe-1b-7b", reduced=False)
     config = EngineConfig(reduced=False, batch=64, max_len=2048,
@@ -152,4 +186,7 @@ def test_olmoe_decode_horizon_fits_four_v5e_chips(four_chips, monkeypatch):
     assert 6.0e9 < mem.argument_size_in_bytes < 6.3e9, mem
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES, mem
     assert mem.alias_size_in_bytes >= 2.68e9, mem
-    assert "paged_decode_attention" in compiled.as_text()
+    text = compiled.as_text()
+    assert "paged_decode_attention" in text
+    assert _arena_copies(text, "bf16[16,5120,16,4,128]",
+                         "bf16[5120,16,4,128]") == []

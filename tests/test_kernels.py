@@ -117,18 +117,21 @@ else:
 # ---------------------------------------------------------------------------
 # paged decode attention
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("layer", [0, 1, 2])
 @pytest.mark.parametrize("blocks_per_wave", [2, 4])
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
                                        (jnp.bfloat16, 3e-2)])
 @pytest.mark.parametrize("group", [1, 2])
 def test_paged_decode_attention_matches_gather(rng, group, dtype, tol,
-                                               blocks_per_wave):
+                                               blocks_per_wave, layer):
     """The kernel over live blocks equals the XLA gather + repeat path on
     every row it serves: lengths 1, bs - 1, bs, bs + 1 and the full table,
     through a shuffled, non-contiguous block table, with read-only shared
     blocks ``-(p + 2)``.  A fully unmapped row comes back finite and
-    leaves the other rows alone."""
-    bs, m, hkv, d = 4, 6, 2, 16
+    leaves the other rows alone.  The arenas are a stack of three layers
+    with distinct contents, read at ``layer``: both reads equal the XLA
+    read of that layer's arena alone (a stack of one)."""
+    bs, m, hkv, d, n_layers = 4, 6, 2, 16, 3
     lengths = np.array([1, bs - 1, bs, bs + 1, m * bs, 2 * bs + 3, 5])
     n_blocks = -(-lengths // bs)
     p = int(n_blocks.sum()) + 3
@@ -139,14 +142,20 @@ def test_paged_decode_attention_matches_gather(rng, group, dtype, tol,
     table[5, :2] = -(table[5, :2] + 2)        # a shared read-only prefix
     table[1, 0] = -(table[1, 0] + 2)
     q = _rand(rng, (len(lengths), hkv * group, d), dtype)
-    k_arena = _rand(rng, (p, bs, hkv, d), dtype)
-    v_arena = _rand(rng, (p, bs, hkv, d), dtype)
-    args = (q, k_arena, v_arena, jnp.asarray(table), jnp.asarray(lengths))
+    k_arena = _rand(rng, (n_layers, p, bs, hkv, d), dtype)
+    v_arena = _rand(rng, (n_layers, p, bs, hkv, d), dtype)
+    rows = (jnp.asarray(table), jnp.asarray(lengths))
+    args = (q, k_arena, v_arena) + rows + (jnp.int32(layer),)
     got = np.asarray(ops.paged_decode_attention(
         *args, impl="interpret", blocks_per_wave=blocks_per_wave),
         np.float32)
-    want = np.asarray(ops.paged_decode_attention(*args, impl="xla"),
-                      np.float32)
+    xla = np.asarray(ops.paged_decode_attention(*args, impl="xla"),
+                     np.float32)
+    one = slice(layer, layer + 1)
+    want = np.asarray(ops.paged_decode_attention(
+        q, k_arena[one], v_arena[one], *rows, jnp.int32(0), impl="xla"),
+        np.float32)
+    np.testing.assert_array_equal(xla, want)
     np.testing.assert_allclose(got[:-1], want[:-1], rtol=tol, atol=tol)
     assert np.isfinite(got[-1]).all()
 

@@ -33,7 +33,7 @@ from repro.core import PagedKVManager, ProgramStore
 from repro.kernels import ops
 from repro.launch.serve import (METRIC_ARENA_OCCUPANCY, METRIC_PAGE_FAULT,
                                 ServingEngine)
-from repro.models import registry, transformer
+from repro.models import attention, registry, transformer
 from repro.sharding import make_rules
 
 TESTS = Path(__file__).resolve().parent
@@ -384,6 +384,50 @@ def test_paged_kernel_decode_matches_xla_path(monkeypatch):
     np.testing.assert_allclose(k_logits, x_logits, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(k_horizon, k_toks)
     np.testing.assert_array_equal(x_horizon, x_toks)
+
+
+def test_paged_kernel_horizon_with_tail_layer_matches_dense(monkeypatch):
+    """A model of two scanned groups of two layers and one unscanned tail
+    layer, whose arenas the layer scan carries as stacks (the tail's as a
+    stack of one): ``decode_horizon`` and single paged steps give the same
+    greedy tokens with the kernel (interpreted) as with the XLA read, and
+    both give the tokens and logits of the dense (unpaged) decode over the
+    same context, which has no layer index to get wrong."""
+    cfg = registry.get_config("qwen3-0.6b", reduced=True).replace(
+        n_layers=5, layer_pattern=("G", "G"))
+    assert transformer.split_layers(cfg)[1:] == (2, ("G",))
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    streams = {impl: _decode_streams(cfg, params, impl, monkeypatch,
+                                     steps=8)
+               for impl in ("interpret", "xla")}
+
+    # the same context as dense per-slot caches: each layer's arena read
+    # out through the block table
+    caches, tok = _paged_decode_setup(cfg)
+    table = caches.pop("block_table")
+
+    def dense(stack):
+        return jnp.stack([attention.gather_paged_kv(stack, layer, table)
+                          for layer in range(len(stack))])
+
+    caches["groups"] = jax.tree.map(dense, caches["groups"])
+    caches["tail"] = jax.tree.map(lambda a: dense(a[None])[0],
+                                  caches["tail"])
+    step = jax.jit(functools.partial(transformer.decode_step, cfg,
+                                     rules=make_rules()))
+    toks, logits = [], []
+    for _ in range(8):
+        out, caches = step(params, caches, tok)
+        tok = transformer.greedy_token(cfg, out)
+        toks.append(np.asarray(tok[:2, 0]))
+        logits.append(np.asarray(out[:2, 0]))
+    toks, logits = np.stack(toks, 1), np.stack(logits, 1)
+
+    for impl, (p_toks, p_logits, p_horizon) in streams.items():
+        np.testing.assert_array_equal(p_toks, toks, err_msg=impl)
+        np.testing.assert_array_equal(p_horizon, toks, err_msg=impl)
+        np.testing.assert_allclose(p_logits, logits, rtol=1e-4, atol=1e-4,
+                                   err_msg=impl)
 
 
 @pytest.mark.parametrize("case", ["kernel", "window", "sharded_kv_heads",

@@ -260,23 +260,26 @@ def test_preempted_shared_head_unpins_evicts_and_faults_back():
 def test_shared_encoding_gathers_reads_and_drops_writes():
     """``-(phys + 2)`` is the whole write protection: the gather decodes
     it to the physical block while the write path's ``phys >= 0`` guard
-    silently drops any write aimed at it."""
-    arena = jnp.arange(4 * 2, dtype=jnp.float32).reshape(4, 2, 1, 1)
+    silently drops any write aimed at it (one layer's arena, as a stack of
+    one)."""
+    arena = jnp.arange(4 * 2, dtype=jnp.float32).reshape(1, 4, 2, 1, 1)
     bt = jnp.asarray([[encode_shared(1), 2], [-1, -1]], jnp.int32)
-    out = attention.gather_paged_kv(arena, bt)
+    out = attention.gather_paged_kv(arena, 0, bt)
     np.testing.assert_array_equal(np.asarray(out[0, :2]),
-                                  np.asarray(arena[1]))   # shared decodes
+                                  np.asarray(arena[0, 1]))  # shared decodes
     np.testing.assert_array_equal(np.asarray(out[0, 2:]),
-                                  np.asarray(arena[2]))   # private reads
+                                  np.asarray(arena[0, 2]))  # private reads
 
     val = jnp.full((2, 1, 1), 99.0)
     live = jnp.asarray([True, False])
     # pos 0 -> logical block 0 -> shared mapping: the write must drop
-    a2 = attention.write_paged_kv(arena, bt, jnp.asarray([0, 0]), val, live)
+    a2 = attention.write_paged_kv(arena, 0, bt, jnp.asarray([0, 0]), val,
+                                  live)
     np.testing.assert_array_equal(np.asarray(a2), np.asarray(arena))
     # pos 2 -> logical block 1 -> private block 2: the write lands
-    a3 = attention.write_paged_kv(arena, bt, jnp.asarray([2, 0]), val, live)
-    assert float(a3[2, 0, 0, 0]) == 99.0
+    a3 = attention.write_paged_kv(arena, 0, bt, jnp.asarray([2, 0]), val,
+                                  live)
+    assert float(a3[0, 2, 0, 0, 0]) == 99.0
 
 
 # ---------------------------------------------------------------------------
